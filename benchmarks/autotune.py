@@ -780,29 +780,31 @@ def bench_mesh_overlap() -> Dict:
     would perturb the training numerics every other scenario's baseline
     was recorded under (multi-device XLA compiles the same program
     slightly differently).  So when this process has one device, the
-    measurement runs in a subprocess with the flag set; the rest of the
-    bench stays on the single-device numerics CI replays."""
+    measurement runs in a CPU subprocess with the flag appended; the rest
+    of the bench stays on the single-device numerics CI replays.  A failed
+    subprocess fails the bench."""
     import jax
 
     if jax.device_count() >= 4:
         return _mesh_overlap_here()
     import subprocess
     import sys
+    flags = os.environ.get("XLA_FLAGS", "")
     env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               XLA_FLAGS=f"{flags} --xla_force_host_platform_device_count=4"
+               .strip(),
                JAX_PLATFORMS="cpu",
                PYTHONPATH=os.pathsep.join(
                    [os.path.join(HERE, "..", "src"),
                     os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep))
-    try:
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.autotune", "--mesh-overlap"],
-            env=env, cwd=os.path.join(HERE, ".."), capture_output=True,
-            text=True, timeout=600, check=True)
-        return json.loads(out.stdout.strip().splitlines()[-1])
-    except (subprocess.SubprocessError, json.JSONDecodeError, OSError,
-            IndexError) as e:   # IndexError: empty stdout on a 0 exit
-        return {"skipped": f"4-device subprocess failed: {e}"}
+    out = subprocess.run(
+        [sys.executable, "-m", "benchmarks.autotune", "--mesh-overlap"],
+        env=env, cwd=os.path.join(HERE, ".."), capture_output=True,
+        text=True, timeout=600)
+    if out.returncode != 0:
+        raise RuntimeError(f"4-device mesh-overlap subprocess exited "
+                           f"{out.returncode}:\n{out.stderr[-3000:]}")
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def bench_autotune() -> Dict:
@@ -944,9 +946,8 @@ def bench_autotune() -> Dict:
         "topology_shapes_share_numerics":
             tv["ring"]["final_loss"] == tv["tree"]["final_loss"],
     })
-    if "overlap_speedup" in report["mesh_overlap"]:
-        report["acceptance"]["mesh_overlap_speedup_measured"] = \
-            report["mesh_overlap"]["overlap_speedup"] > 1.0
+    report["acceptance"]["mesh_overlap_speedup_measured"] = \
+        report["mesh_overlap"]["overlap_speedup"] > 1.0
     os.makedirs(OUT_DIR, exist_ok=True)
     with open(OUT_PATH, "w") as f:
         json.dump(report, f, indent=1)
@@ -976,13 +977,10 @@ def _print_report(r: Dict) -> None:
           f"{m['n_retunes']} retunes, max_ef {m['max_ef_ratio']}, "
           f"final {m['final_config']}")
     mo = r["mesh_overlap"]
-    if "overlap_speedup" in mo:
-        print(f"mesh overlap ({mo['n_devices']} devices, {mo['chunks']} "
-              f"chunks @ {mo['emulate_mbps']} Mbps emulated): "
-              f"{mo['overlap_speedup']}x (serial {mo['t_serialized_s']}s "
-              f"-> pipelined {mo['t_pipelined_s']}s)")
-    else:
-        print(f"mesh overlap: {mo['skipped']}")
+    print(f"mesh overlap ({mo['n_devices']} devices, {mo['chunks']} "
+          f"chunks @ {mo['emulate_mbps']} Mbps emulated): "
+          f"{mo['overlap_speedup']}x (serial {mo['t_serialized_s']}s "
+          f"-> pipelined {mo['t_pipelined_s']}s)")
     st = r["streaming"]
     sv = st["variants"]["streaming"]
     rv = st["variants"]["round_adaptive"]
@@ -1051,9 +1049,10 @@ def main(argv: Sequence[str] = None) -> Dict:
     args = ap.parse_args(argv)
     if args.mesh_overlap:
         import jax
-        rep = (_mesh_overlap_here() if jax.device_count() >= 4
-               else {"skipped": f"needs >= 4 devices, have "
-                                f"{jax.device_count()}"})
+        if jax.device_count() < 4:
+            raise SystemExit(f"--mesh-overlap needs >= 4 devices, have "
+                             f"{jax.device_count()}")
+        rep = _mesh_overlap_here()
         print(json.dumps(rep))
         return rep
     if args.compare:

@@ -27,7 +27,9 @@ Usage:
   PYTHONPATH=src python -m benchmarks.hillclimb --sync-sweep kimi-k2-1t-a32b
 """
 import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=512"
+                           ).strip()
 
 import argparse
 import json

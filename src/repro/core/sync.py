@@ -724,6 +724,24 @@ def _chunk_widths(cfg: SyncConfig, n_total: int) -> Tuple[int, ...]:
     return tuple(min(step, n_total - lo) for lo in range(0, n_total, step))
 
 
+def _per_pod(fn, *arrays):
+    """``jax.vmap(fn)`` over the leading pod dim of ``arrays``.
+
+    On a mesh with a ``"pod"`` axis the map runs under ``shard_map``, so
+    each device codes only the pods it holds: a Pallas kernel has no
+    partitioning rule, and the SPMD partitioner refuses it rather than
+    gather every pod's buffer onto every device."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.sharding.rules import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None or dict(mesh.shape).get("pod", 1) == 1:
+        return jax.vmap(fn)(*arrays)
+    return jax.shard_map(jax.vmap(fn), mesh=mesh, in_specs=P("pod"),
+                         out_specs=P("pod"), check_vma=False)(*arrays)
+
+
 def _encode_bucket(cfg: SyncConfig, flat: jnp.ndarray, want_local: bool
                    ) -> Tuple[Tuple[ChunkPayload, ...],
                               Optional[jnp.ndarray]]:
@@ -748,10 +766,10 @@ def _encode_bucket(cfg: SyncConfig, flat: jnp.ndarray, want_local: bool
     for m in _chunk_widths(cfg, n_total):
         seg = flat[:, off:off + m]
         off += m
-        q, idx, scales = jax.vmap(lambda f: encode(f, k_block))(seg)
+        q, idx, scales = _per_pod(lambda f: encode(f, k_block), seg)
         if want_local:
-            local_parts.append(jax.vmap(
-                lambda a, i, s: decode(a, i, s, m))(q, idx, scales))
+            local_parts.append(_per_pod(
+                lambda a, i, s: decode(a, i, s, m), q, idx, scales))
         chunks.append(ChunkPayload(q=q, idx=idx.astype(jnp.uint16),
                                    scales=scales))
     local = jnp.concatenate(local_parts, axis=1) if want_local else None
@@ -768,8 +786,8 @@ def _decode_chunks(cfg: SyncConfig, chunks: Sequence[ChunkPayload],
 
     block = min(cfg.codec_block, max(1, n_total))
     _, decode = kops.wan_codec_fns(block=block, value_dtype=cfg.value_dtype)
-    parts = [jax.vmap(lambda a, i, s: decode(a, i, s, m))(
-        c.q, c.idx.astype(jnp.int32), c.scales)
+    parts = [_per_pod(lambda a, i, s: decode(a, i, s, m),
+                      c.q, c.idx.astype(jnp.int32), c.scales)
         for c, m in zip(chunks, widths)]
     return jnp.concatenate(parts, axis=1)
 

@@ -19,20 +19,22 @@ Selection algorithm (threshold refinement, no O(k) serialization):
    ``count(keys >= candidate)``, each a fully vectorized compare+reduce over
    the whole tile.  Work is O(16 * block) independent of k.
 4. Select ``keys > T`` plus the first (by index) ties at ``T``; exact ranks
-   come from a cumulative sum — again vectorized, never serialized.
+   come from a prefix sum done as log-step lane rotations and adds —
+   again vectorized, never serialized.
 5. Compact the winners with a one-hot dot product (the TPU-native scatter:
    MXU contraction instead of unsupported vector scatters).  Each one-hot
-   column has exactly one nonzero, so fp32 accumulation is exact; local
-   indices stay < block <= 2^16, exactly representable in fp32.
+   column has exactly one nonzero and the dot runs at ``HIGHEST``
+   precision, so the gathered fp32 values and the local indices
+   (< block <= 2^16) come out exact.
 6. Quantize the selected values to int8 against a per-block scale
    ``max|x| / 127`` — fused into the same kernel, so the fp32 payload never
    round-trips through HBM.
 
-Tile geometry: each grid step processes ``rows_per_step`` independent blocks
-as a 2D (rows, block) tile — the VPU-natural sublane x lane layout.  All of
-the selection math above batches trivially over the row dimension, so one
-kernel dispatch selects/quantizes several blocks (amortizing grid overhead
-the same way the sync layer's bucketing amortizes per-leaf dispatch).
+Tile geometry: each grid step processes ``ROWS`` = 8 independent blocks as
+a 2D (8, block) tile — one fp32 sublane tile, the VPU-natural layout; a
+block row is padded to whole 128-lane vregs.  The selection math batches
+over the rows; the one-hot gather then walks the tile one block row (and,
+at high k, one lane chunk) at a time so its tile stays within VMEM.
 
 Wire format per block of ``block`` elements: ``k_block`` encoded values +
 ``k_block`` block-local indices (< 2^16, i.e. u16 on the wire; int32 in
@@ -64,6 +66,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # keep the top 16 of the 31 magnitude bits (sign bit of |x| is always 0):
 # bits 30..23 exponent, 22..15 top mantissa byte
@@ -82,13 +85,14 @@ INV_FP8_MAX = 1.0 / 448.0
 VALUE_DTYPES = ("int8", "fp8", "int4")  # the codec's precision ladder
 
 DEFAULT_BLOCK = 4096
-DEFAULT_ROWS = 8                       # blocks per grid step (VMEM-bounded)
+ROWS = 8                               # blocks per grid step: one f32 sublane tile
 
-# the (rows, block, k_block) fp32 one-hot tile is the kernels' VMEM
-# high-water mark; cap it so the compiled TPU path fits comfortably under
-# the ~16 MB/core budget at ANY compress fraction (rows degrades toward 1
-# as k_block grows — the selection math is per-row, so tiling is free)
-_ONEHOT_BUDGET_BYTES = 8 << 20
+# the (k_block, chunk) fp32 one-hot tile of one block row is the kernels'
+# VMEM high-water mark; the gather walks the block in lane chunks small
+# enough to keep it under budget at ANY compress fraction (the rows per
+# grid step stay at one sublane tile — chunking is semantics-free)
+_ONEHOT_BUDGET_BYTES = 2 << 20
+_LANES = 128
 
 
 def k_per_block(block: int, frac: float) -> int:
@@ -96,16 +100,40 @@ def k_per_block(block: int, frac: float) -> int:
     return max(1, min(block, int(round(block * frac))))
 
 
-def _cap_rows(rows: int, block: int, k_block: int) -> int:
-    return max(1, min(rows, _ONEHOT_BUDGET_BYTES // (4 * block * k_block)))
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
-def _select_mask(x: jnp.ndarray, k_block: int):
+def _onehot_chunk(lanes: int, k_block: int) -> int:
+    """Lane width of one gather step over a ``lanes``-wide block row: the
+    whole row, halved (staying a multiple of 128) while the one-hot tile
+    exceeds the budget."""
+    chunk = lanes
+    while chunk * _round_up(k_block, _LANES) * 4 > _ONEHOT_BUDGET_BYTES \
+            and chunk % (2 * _LANES) == 0:
+        chunk //= 2
+    return chunk
+
+
+def _cumsum_lanes(v: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum along the last axis: log-step roll-and-add
+    (Mosaic has no cumsum lowering; a lane rotate is native)."""
+    n = v.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+    shift = 1
+    while shift < n:
+        v = v + jnp.where(lane >= shift, pltpu.roll(v, shift, v.ndim - 1), 0)
+        shift *= 2
+    return v
+
+
+def _select_slots(x: jnp.ndarray, k_block: int):
     """Exact block-local top-k selection over a (rows, block) tile.
 
-    Returns (mask bool, pos int32) both (rows, block), and maxabs (rows,).
-    Selection key: |x| truncated to KEY_MASK bits; ties broken by lowest
-    index (matching ``jax.lax.top_k``'s stable ordering in the oracle).
+    Returns (slot int32 (rows, block): the winner's output slot, in index
+    order, or -1 for a loser; maxabs f32 (rows, 1)).  Selection key: |x|
+    truncated to KEY_MASK bits; ties broken by lowest index (matching
+    ``jax.lax.top_k``'s stable ordering in the oracle).
     """
     mag = jnp.abs(x)
     bits = jax.lax.bitcast_convert_type(mag, jnp.int32) & KEY_MASK
@@ -115,45 +143,46 @@ def _select_mask(x: jnp.ndarray, k_block: int):
     # compare+reduce on the full tile each round
     def refine(i, t):
         cand = t | (jnp.int32(1) << (30 - i))
-        cnt = jnp.sum((bits >= cand[:, None]).astype(jnp.int32), axis=1)
+        cnt = jnp.sum((bits >= cand).astype(jnp.int32), axis=1, keepdims=True)
         return jnp.where(cnt >= k_block, cand, t)
 
     thresh = jax.lax.fori_loop(
-        0, _N_KEY_BITS, refine, jnp.zeros((x.shape[0],), jnp.int32))
+        0, _N_KEY_BITS, refine, jnp.zeros((x.shape[0], 1), jnp.int32))
 
-    above = bits > thresh[:, None]
-    n_above = jnp.sum(above.astype(jnp.int32), axis=1)
-    at = bits == thresh[:, None]
+    above = bits > thresh
+    n_above = jnp.sum(above.astype(jnp.int32), axis=1, keepdims=True)
+    at = bits == thresh
     # first (k_block - n_above) ties by index, exactly filling k_block
-    tie_rank = jnp.cumsum(at.astype(jnp.int32), axis=1) - 1
-    mask = above | (at & (tie_rank < (k_block - n_above)[:, None]))
-    pos = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1   # slot, by index
-    return mask, pos, jnp.max(mag, axis=1)
+    tie_rank = _cumsum_lanes(at.astype(jnp.int32)) - 1
+    mask = above | (at & (tie_rank < k_block - n_above))
+    pos = _cumsum_lanes(mask.astype(jnp.int32)) - 1       # slot, by index
+    return jnp.where(mask, pos, -1), jnp.max(mag, axis=1, keepdims=True)
 
 
 def _quantize(vals: jnp.ndarray, maxabs: jnp.ndarray, value_dtype: str
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-tier value encoding of a (rows, k_block) tile of selected values.
+    """Per-tier value encoding of a (rows, k_block) tile of selected values
+    against the (rows, 1) block maxima.
 
-    Returns (q int8, scale f32 (rows,)).  ``q`` is always an int8 *container*:
-    the int4 tier's [-7, 7] codes are nibble-packed by the wrapper (packing is
-    a pure bit shuffle, not kernel work), the fp8 tier ships the e4m3 bit
-    pattern bitcast to int8.  All three run identically in the oracle — the
-    expressions below are the bit-level spec.
+    Returns (q int8, scale f32 (rows, 1)).  ``q`` is always an int8
+    *container*: the int4 tier's [-7, 7] codes are nibble-packed by the
+    wrapper (packing is a pure bit shuffle, not kernel work), the fp8 tier
+    ships the e4m3 bit pattern bitcast to int8.  All three run identically
+    in the oracle — the expressions below are the bit-level spec.
     """
     if value_dtype == "int8":
         scale = jnp.where(maxabs > 0, maxabs * jnp.float32(INV_127), 1.0)
-        q = jnp.clip(jnp.round(vals / scale[:, None]), -127.0, 127.0)
+        q = jnp.clip(jnp.round(vals / scale), -127.0, 127.0)
         return q.astype(jnp.int8), scale
     if value_dtype == "int4":
         scale = jnp.where(maxabs > 0, maxabs * jnp.float32(INV_7), 1.0)
-        q = jnp.clip(jnp.round(vals / scale[:, None]), -7.0, 7.0)
+        q = jnp.clip(jnp.round(vals / scale), -7.0, 7.0)
         return q.astype(jnp.int8), scale
     if value_dtype == "fp8":
         # map the block max onto e4m3's largest finite value, clip the 1-ulp
         # overshoot the fp32 reciprocal can introduce, ship the bit pattern
         scale = jnp.where(maxabs > 0, maxabs * jnp.float32(INV_FP8_MAX), 1.0)
-        f8 = jnp.clip(vals / scale[:, None], -FP8_MAX, FP8_MAX
+        f8 = jnp.clip(vals / scale, -FP8_MAX, FP8_MAX
                       ).astype(jnp.float8_e4m3fn)
         return jax.lax.bitcast_convert_type(f8, jnp.int8), scale
     raise ValueError(f"unknown value_dtype {value_dtype!r} "
@@ -162,13 +191,14 @@ def _quantize(vals: jnp.ndarray, maxabs: jnp.ndarray, value_dtype: str
 
 def _dequantize(q: jnp.ndarray, scales: jnp.ndarray, value_dtype: str
                 ) -> jnp.ndarray:
-    """Inverse of :func:`_quantize` ((rows, k) int8 container -> f32)."""
+    """Inverse of :func:`_quantize` ((rows, k) int8 container, (rows, 1)
+    scales -> f32)."""
     if value_dtype == "fp8":
         v = jax.lax.bitcast_convert_type(q, jnp.float8_e4m3fn
                                          ).astype(jnp.float32)
     else:                                   # int8 / (unpacked) int4 codes
         v = q.astype(jnp.float32)
-    return v * scales[..., None]
+    return v * scales
 
 
 def pack_nibbles(q: jnp.ndarray) -> jnp.ndarray:
@@ -193,118 +223,145 @@ def unpack_nibbles(p: jnp.ndarray, k: int) -> jnp.ndarray:
     return signed[..., :k].astype(jnp.int8)
 
 
-def _encode_kernel(x_ref, q_ref, idx_ref, scale_ref, *, k_block: int,
-                   block: int, rows: int, value_dtype: str):
-    x = x_ref[...].astype(jnp.float32)                  # (rows, block)
-    mask, pos, maxabs = _select_mask(x, k_block)
+# the one-hot gathers are exact only if the MXU keeps every fp32 bit of
+# the gathered values and of the indices: at the TPU's default precision
+# fp32 operands may pass through bf16, which holds integers only to 256
+_EXACT = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))                          # a @ b.T
 
-    # one-hot compaction: (rows, block, k_block) with exactly one 1 per
-    # output column -> the batched dot is an exact gather on the MXU
-    slots = jax.lax.broadcasted_iota(jnp.int32, (rows, block, k_block), 2)
-    onehot = (mask[..., None] & (pos[..., None] == slots)).astype(jnp.float32)
-    dims = (((1,), (1,)), ((0,), (0,)))                 # contract block, batch rows
-    vals = jax.lax.dot_general(onehot, x, dims,
-                               preferred_element_type=jnp.float32)
-    iota = jax.lax.broadcasted_iota(jnp.float32, (rows, block), 1)
-    idxf = jax.lax.dot_general(onehot, iota, dims,      # exact: < 2^16 < 2^24
-                               preferred_element_type=jnp.float32)
 
-    q, scale = _quantize(vals, maxabs, value_dtype)
+def _encode_kernel(x_ref, q_ref, idx_ref, scale_ref, slot_ref, vals_ref, *,
+                   k_block: int, chunk: int, value_dtype: str):
+    rows, lanes = x_ref.shape
+    slot, maxabs = _select_slots(x_ref[...].astype(jnp.float32), k_block)
+    slot_ref[...] = slot
 
+    # one-hot compaction, one block row and one lane chunk at a time: the
+    # (k_block, chunk) one-hot has exactly one 1 per winner, so the dot is
+    # an exact gather on the MXU.  Row 0 of the left operand carries the
+    # values, row 1 the lane indices
+    sub = jax.lax.broadcasted_iota(jnp.int32, (ROWS, chunk), 0)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (k_block, chunk), 0)
+    out_row = jax.lax.broadcasted_iota(jnp.int32, (ROWS, k_block), 0)
+    for r in range(rows):
+        acc = jnp.zeros((ROWS, k_block), jnp.float32)
+        for c in range(0, lanes, chunk):
+            onehot = (slots == slot_ref[r:r + 1, c:c + chunk]
+                      ).astype(jnp.float32)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) + c
+            lhs = jnp.where(sub == 0, x_ref[r:r + 1, c:c + chunk]
+                            .astype(jnp.float32), lane.astype(jnp.float32))
+            acc = acc + jax.lax.dot_general(
+                lhs, onehot, _NT, precision=_EXACT,
+                preferred_element_type=jnp.float32)
+        vals_ref[r:r + 1, :] = jnp.sum(jnp.where(out_row == 0, acc, 0.0),
+                                       axis=0, keepdims=True)
+        idx_ref[r:r + 1, :] = jnp.sum(jnp.where(out_row == 1, acc, 0.0),
+                                      axis=0, keepdims=True
+                                      ).astype(jnp.int32)
+
+    q, scale = _quantize(vals_ref[...], maxabs, value_dtype)
     q_ref[...] = q
-    idx_ref[...] = idxf.astype(jnp.int32)
     scale_ref[...] = scale
 
 
-def _decode_kernel(q_ref, idx_ref, scale_ref, out_ref, *, block: int,
-                   rows: int, value_dtype: str):
-    v = _dequantize(q_ref[...], scale_ref[...], value_dtype)
-    idx = idx_ref[...]                                  # (rows, k_block)
+def _decode_kernel(q_ref, idx_ref, scale_ref, out_ref, *, chunk: int,
+                   value_dtype: str):
+    rows, lanes = out_ref.shape
+    k_block = idx_ref.shape[1]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, k_block), 0)
     # transpose of the encode compaction: one nonzero per column -> exact
-    cols = jax.lax.broadcasted_iota(jnp.int32, (rows, block, idx.shape[1]), 1)
-    onehot = (cols == idx[:, None, :]).astype(jnp.float32)
-    out_ref[...] = jax.lax.dot_general(
-        onehot, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    for r in range(rows):
+        v = _dequantize(q_ref[r:r + 1, :], scale_ref[r:r + 1, :], value_dtype)
+        idx = idx_ref[r:r + 1, :]
+        for c in range(0, lanes, chunk):
+            onehot = (cols + c == idx).astype(jnp.float32)
+            out_ref[r:r + 1, c:c + chunk] = jax.lax.dot_general(
+                v, onehot, _NT, precision=_EXACT,
+                preferred_element_type=jnp.float32)
 
 
-def _geometry(n: int, block: int, rows: int, k_block: int
-              ) -> Tuple[int, int, int, int]:
-    """(block, rows, nb_real, nb_padded): pad n up to whole (rows x block)
-    tiles; padded blocks are all-zero and sliced off the outputs.  ``rows``
-    is capped by the one-hot VMEM budget (tiling never changes results)."""
+def _geometry(n: int, block: int, k_block: int
+              ) -> Tuple[int, int, int, int, int]:
+    """(block, k_block, lanes, nb_real, nb_padded): pad n up to whole
+    (ROWS x block) tiles and each block row up to whole 128-lane vregs.
+    Padded blocks and lanes are all-zero and sliced off the outputs; a
+    zero pad lane never displaces a real element (ties go to the lowest
+    index, and a real block always has at least ``k_block`` elements)."""
     block = min(block, n)
     nb = -(-n // block)
-    rows = min(_cap_rows(rows, block, min(k_block, block)), nb)
-    nb_pad = -(-nb // rows) * rows
-    return block, rows, nb, nb_pad
+    return (block, min(k_block, block), _round_up(block, _LANES), nb,
+            _round_up(nb, ROWS))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k_block", "block", "rows", "value_dtype",
+                   static_argnames=("k_block", "block", "value_dtype",
                                     "interpret"))
 def wan_encode_pallas(
     x: jnp.ndarray, k_block: int, *, block: int = DEFAULT_BLOCK,
-    rows: int = DEFAULT_ROWS, value_dtype: str = "int8",
-    interpret: bool = False,
+    value_dtype: str = "int8", interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """x: flat (n,) -> (payload, local idx int32 (nb*k_block,), scales f32
     (nb,)); nb = ceil(n / block).  Payload: int8 (nb*k_block,) for
     int8/fp8 (fp8 ships its bit pattern), uint8 (nb*ceil(k_block/2),)
     nibble-packed for int4."""
     n = x.shape[0]
-    block, rows, nb, nb_pad = _geometry(n, block, rows, k_block)
-    k_block = min(k_block, block)
+    block, k_block, lanes, nb, nb_pad = _geometry(n, block, k_block)
     xp = jnp.pad(x, (0, nb_pad * block - n)).reshape(nb_pad, block)
+    xp = jnp.pad(xp, ((0, 0), (0, lanes - block)))
+    row = lambda b: (b, 0)                              # noqa: E731
 
     q, idx, scales = pl.pallas_call(
-        functools.partial(_encode_kernel, k_block=k_block, block=block,
-                          rows=rows, value_dtype=value_dtype),
-        grid=(nb_pad // rows,),
-        in_specs=[pl.BlockSpec((rows, block), lambda b: (b, 0))],
-        out_specs=[pl.BlockSpec((rows, k_block), lambda b: (b, 0)),
-                   pl.BlockSpec((rows, k_block), lambda b: (b, 0)),
-                   pl.BlockSpec((rows,), lambda b: (b,))],
+        functools.partial(_encode_kernel, k_block=k_block,
+                          chunk=_onehot_chunk(lanes, k_block),
+                          value_dtype=value_dtype),
+        grid=(nb_pad // ROWS,),
+        in_specs=[pl.BlockSpec((ROWS, lanes), row)],
+        out_specs=[pl.BlockSpec((ROWS, k_block), row),
+                   pl.BlockSpec((ROWS, k_block), row),
+                   pl.BlockSpec((ROWS, 1), row)],
         out_shape=[jax.ShapeDtypeStruct((nb_pad, k_block), jnp.int8),
                    jax.ShapeDtypeStruct((nb_pad, k_block), jnp.int32),
-                   jax.ShapeDtypeStruct((nb_pad,), jnp.float32)],
+                   jax.ShapeDtypeStruct((nb_pad, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((ROWS, lanes), jnp.int32),
+                        pltpu.VMEM((ROWS, k_block), jnp.float32)],
         interpret=interpret,
     )(xp)
-    q, idx, scales = q[:nb], idx.reshape(-1)[:nb * k_block], scales[:nb]
+    q, idx, scales = q[:nb], idx[:nb].reshape(-1), scales[:nb, 0]
     if value_dtype == "int4":
         q = pack_nibbles(q)          # per-block rows -> wire bytes
     return q.reshape(-1), idx, scales
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n", "block", "rows", "value_dtype",
-                                    "interpret"))
+                   static_argnames=("n", "block", "value_dtype", "interpret"))
 def wan_decode_pallas(
     q: jnp.ndarray, idx: jnp.ndarray, scales: jnp.ndarray, n: int, *,
-    block: int = DEFAULT_BLOCK, rows: int = DEFAULT_ROWS,
-    value_dtype: str = "int8", interpret: bool = False,
+    block: int = DEFAULT_BLOCK, value_dtype: str = "int8",
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Inverse of :func:`wan_encode_pallas` -> dense (n,) fp32."""
     # k_block from the index array — the int4 payload is nibble-packed, so
     # q's length is not k_block-shaped for every tier
     k_block = idx.shape[0] // (-(-n // min(block, n)))
-    block, rows, nb, nb_pad = _geometry(n, block, rows, k_block)
+    block, k_block, lanes, nb, nb_pad = _geometry(n, block, k_block)
     if value_dtype == "int4":
         q = unpack_nibbles(q.reshape(nb, -1), k_block)
 
-    def pad_rows(a, fill=0):
-        a = a.reshape(nb, -1)
-        return jnp.pad(a, ((0, nb_pad - nb), (0, 0)), constant_values=fill)
+    def pad_rows(a):
+        return jnp.pad(a.reshape(nb, -1), ((0, nb_pad - nb), (0, 0)))
 
+    row = lambda b: (b, 0)                              # noqa: E731
     dense = pl.pallas_call(
-        functools.partial(_decode_kernel, block=block, rows=rows,
+        functools.partial(_decode_kernel,
+                          chunk=_onehot_chunk(lanes, k_block),
                           value_dtype=value_dtype),
-        grid=(nb_pad // rows,),
-        in_specs=[pl.BlockSpec((rows, k_block), lambda b: (b, 0)),
-                  pl.BlockSpec((rows, k_block), lambda b: (b, 0)),
-                  pl.BlockSpec((rows,), lambda b: (b,))],
-        out_specs=pl.BlockSpec((rows, block), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb_pad, block), jnp.float32),
+        grid=(nb_pad // ROWS,),
+        in_specs=[pl.BlockSpec((ROWS, k_block), row),
+                  pl.BlockSpec((ROWS, k_block), row),
+                  pl.BlockSpec((ROWS, 1), row)],
+        out_specs=pl.BlockSpec((ROWS, lanes), row),
+        out_shape=jax.ShapeDtypeStruct((nb_pad, lanes), jnp.float32),
         interpret=interpret,
-    )(pad_rows(q), pad_rows(idx), jnp.pad(scales, (0, nb_pad - nb)))
-    return dense.reshape(-1)[:n]
+    )(pad_rows(q), pad_rows(idx), pad_rows(scales))
+    return dense[:nb, :block].reshape(-1)[:n]

@@ -29,29 +29,6 @@ from repro.training.trainer import Trainer, TrainerConfig, TrainState
 Pytree = Any
 
 
-def ensure_partitionable_threefry() -> None:
-    """Pin the sharding-invariant RNG before any sharded-launch tracing.
-
-    Under jax<0.5 the default (non-partitionable) threefry lowering is not
-    sharding-invariant — the SPMD partitioner splits the counter stream per
-    device, so a jitted init with sharded out_shardings draws DIFFERENT
-    initial parameters than the same init run unsharded (observed as the
-    ~0.39 loss divergence on the 8-device debug mesh).  The partitionable
-    implementation generates identical bits regardless of how the consumer
-    is partitioned (and is the jax>=0.5 default).
-
-    NOTE: jax.config is process-global and the partitionable stream is a
-    *different* bit-stream, so this is a deliberate function call at the
-    sharded-launch entry point (``make_train_setup``), not an import side
-    effect: merely importing launch helpers never flips a process's seeded
-    draws mid-stream.  Emulation-only processes keep the legacy streams;
-    any process that builds a sharded setup gets the partitionable stream
-    consistently for sharded AND unsharded execution from that point on —
-    exactly the invariance the parity tests need.
-    """
-    jax.config.update("jax_threefry_partitionable", True)
-
-
 def train_rules() -> Dict:
     r = dict(DEFAULT_RULES)
     r.update({
@@ -164,7 +141,6 @@ def make_train_setup(arch: Arch, mesh: Mesh, *,
                      optimizer: str = "sgd", lr: float = 0.01,
                      smoke: bool = False,
                      config_overrides: Optional[dict] = None) -> TrainSetup:
-    ensure_partitionable_threefry()
     cfg = arch.smoke if smoke else arch.config
     if config_overrides:
         cfg = cfg.replace(**config_overrides)

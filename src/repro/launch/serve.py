@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_arch
 from repro.core.control_plane import CloudEvent, ServingElasticityController
+from repro.launch.cache import enable_compile_cache
 from repro.models.registry import get_model_fns
 from repro.serving.engine import (BatchScheduler, ContinuousEngine,
                                   ContinuousScheduler, ServingEngine)
@@ -70,6 +71,7 @@ def main(argv=None):
                          "immediate, scale-down after hysteresis) instead "
                          "of taking --replicas literally")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     cfg = arch.smoke if args.smoke else arch.config

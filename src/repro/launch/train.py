@@ -20,6 +20,10 @@ Examples:
   # any assigned architecture at smoke scale
   PYTHONPATH=src python -m repro.launch.train --arch gemma2-27b --smoke \
       --steps 50
+
+  # an arch at its published widths, cut to 4 layers (one v5e chip)
+  PYTHONPATH=src python -m repro.launch.train --arch mamba2-1.3b \
+      --layers 4 --pods 2 --batch 4 --seq 2048 --steps 6
 """
 from __future__ import annotations
 
@@ -58,6 +62,7 @@ from repro.core.transport import (MeasuredWanProbe, MeshTransport,
                                   SimTransport)
 from repro.core.wan import BandwidthTrace, WANConfig
 from repro.data.pipeline import TokenStream
+from repro.launch.cache import enable_compile_cache
 from repro.models.registry import get_model_fns
 from repro.training.trainer import (LiveMigrator, Trainer, TrainerConfig,
                                     apply_reconfig)
@@ -337,13 +342,23 @@ def preset_tiny():
                  compute_dtype="float32", remat="none")
 
 
-def main(argv=None):
+def main(argv=None, *, on_finish=None):
+    """Run the driver on ``argv`` and return the summary dict.
+
+    ``on_finish(trainer, state, losses)``, when given, is called after the
+    last step with the live trainer, the final train state and the
+    per-step losses — the hook an embedding caller inspects them through.
+    """
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--preset", choices=["100m", "tiny"],
                     help="built-in config instead of --arch")
     ap.add_argument("--smoke", action="store_true",
                     help="use the arch's reduced smoke config")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the arch's depth to N layers (a multiple of "
+                         "its layer-pattern period), keeping every "
+                         "published width")
     ap.add_argument("--pods", type=int, default=2)
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8, help="global batch")
@@ -489,6 +504,7 @@ def main(argv=None):
                          "encoder-decoder modules print a skip.  See "
                          "docs/serving.md")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # ----------------------------------------------------------- model
     if args.preset or (not args.arch):
@@ -500,6 +516,12 @@ def main(argv=None):
         cfg = arch.smoke if args.smoke else arch.config
         module = arch.module
         name = cfg.name
+    if args.layers:
+        if args.layers < 0 or args.layers % cfg.period:
+            raise SystemExit(
+                f"--layers {args.layers}: {cfg.name} needs a positive "
+                f"multiple of its layer-pattern period {cfg.period}")
+        cfg = cfg.replace(n_layers=args.layers)
     fns = get_model_fns(module)
 
     # ----------------------------------------------------- control plane
@@ -960,6 +982,9 @@ def main(argv=None):
             ckpt.save(args.ckpt_dir, state.params, step=step + 1,
                       metadata={"model": name, "sync": args.sync})
 
+    if on_finish is not None:
+        on_finish(trainer, state, losses)
+
     last_durable = None
     if engine is not None:
         engine.wait()
@@ -1000,7 +1025,8 @@ def main(argv=None):
                   f"{serve_info['decode_steps']} pool decode steps")
 
     summary = {
-        "model": name, "pods": args.pods, "sync": args.sync,
+        "model": name, "layers": cfg.n_layers, "pods": args.pods,
+        "sync": args.sync,
         "interval": args.interval, "steps": args.steps,
         "compress_topk": args.compress_topk, "int8": args.int8,
         "value_dtype": args.value_dtype,

@@ -386,6 +386,12 @@ class Trainer:
     def train_step(self, state, batch):
         return self._train_step(state, batch)
 
+    def sync_step_hlo(self, state: TrainState) -> str:
+        """Optimized HLO of the in-graph sync step compiled for ``state``:
+        what one sync round runs (its Pallas kernels appear as
+        ``tpu_custom_call``s)."""
+        return self._sync_step.lower(state).compile().as_text()
+
     # ------------------------------------------------------ elasticity
     def reconfigure(self, state: TrainState, n_pods: int,
                     keep: Optional[Tuple[int, ...]] = None,

@@ -39,9 +39,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_arch
 from repro.core.sync import SyncConfig
 from repro.launch import context as C
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.shapes import InputShape, train_batch_specs
 from repro.sharding.rules import axis_rules
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_debug_mesh(n_pods=2, data=2, model=2)
 """
 
 

@@ -164,3 +164,35 @@ def test_spec_tree_for_params():
     specs = spec_tree_for_params(tree, ab, {"heads": "model"}, _FakeMesh())
     assert specs["w"] == P("model", None)
     assert specs["b"] == P(None)
+
+
+# ------------------------------------------------------------ mesh / cache
+
+
+def test_pod_mesh_spans_present_devices_with_auto_axes():
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_pod_mesh
+
+    mesh = make_pod_mesh()
+    assert mesh.axis_names == ("pod", "data", "model")
+    assert mesh.devices.shape == (len(jax.devices()), 1, 1)
+    assert all(t == AxisType.Auto for t in mesh.axis_types)
+
+
+def test_compile_cache_dir_env_wins_else_fixed_repo_path(monkeypatch):
+    from repro.launch import cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert cache.enable_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == before   # left to JAX
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = cache.enable_compile_cache()
+        assert path == str(cache.REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -37,3 +37,22 @@ def test_end_to_end_serving():
                     "--prompt-len", "8", "--new-tokens", "4",
                     "--requests", "3"])
     assert len(results) == 3
+
+
+def test_layers_flag_cuts_depth_and_on_finish_sees_the_run():
+    """``--layers`` cuts depth only (the summary records it) and must be a
+    multiple of the layer-pattern period; ``on_finish`` sees every loss."""
+    from repro.launch.train import main
+    seen = {}
+    s = main(["--arch", "mamba2-1.3b", "--smoke", "--layers", "1",
+              "--pods", "2", "--steps", "4", "--batch", "2", "--seq", "16",
+              "--sync", "asgd_ga", "--interval", "2", "--compress-topk",
+              "0.05", "--int8", "--error-feedback", "--log-every", "0"],
+             on_finish=lambda trainer, state, losses: seen.update(
+                 losses=list(losses), pods=trainer.cfg.n_pods))
+    assert s["layers"] == 1
+    assert seen["pods"] == 2 and len(seen["losses"]) == 4
+    assert np.all(np.isfinite(seen["losses"]))
+    with pytest.raises(SystemExit):      # gemma2's pattern period is 2
+        main(["--arch", "gemma2-27b", "--smoke", "--layers", "3",
+              "--steps", "1"])

@@ -91,17 +91,18 @@ def test_selection_energy_close_to_exact_topk():
 
 
 def test_high_k_auto_caps_onehot_tile_and_stays_exact():
-    """At aggressive fractions the (rows, block, k_block) one-hot tile is
-    the VMEM high-water mark; rows must degrade to keep the compiled TPU
-    path under budget, without changing results (tiling is semantics-free).
+    """At aggressive fractions the (k_block, chunk) one-hot tile is the
+    VMEM high-water mark; the gather must walk the block in lane chunks to
+    keep the compiled TPU path under budget, without changing results
+    (tiling is semantics-free).
     """
-    from repro.kernels.wan_codec import _ONEHOT_BUDGET_BYTES, _cap_rows
+    from repro.kernels.wan_codec import _ONEHOT_BUDGET_BYTES, _onehot_chunk
 
     block = 4096
     kb = k_per_block(block, 0.05)            # 205 winners/block
-    rows = _cap_rows(8, block, kb)
-    assert rows * block * kb * 4 <= _ONEHOT_BUDGET_BYTES
-    assert rows < 8                           # the cap actually engaged
+    chunk = _onehot_chunk(block, kb)
+    assert chunk * 256 * 4 <= _ONEHOT_BUDGET_BYTES   # kb pads to 256 lanes
+    assert chunk < block and chunk % 128 == 0  # the cap actually engaged
     x = _rand(1 << 16)
     q1, i1, s1 = wan_encode_pallas(x, kb, block=block, interpret=True)
     q2, i2, s2 = ref.wan_encode(x, kb, block=block)
