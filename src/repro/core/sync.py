@@ -764,15 +764,19 @@ def _encode_bucket(cfg: SyncConfig, flat: jnp.ndarray, want_local: bool
                                         value_dtype=cfg.value_dtype)
     chunks, local_parts, off = [], [], 0
     for m in _chunk_widths(cfg, n_total):
-        seg = flat[:, off:off + m]
-        off += m
-        q, idx, scales = _per_pod(lambda f: encode(f, k_block), seg)
+        with jax.named_scope("sync_encode"):
+            seg = flat[:, off:off + m]
+            off += m
+            q, idx, scales = _per_pod(lambda f: encode(f, k_block), seg)
+            chunks.append(ChunkPayload(q=q, idx=idx.astype(jnp.uint16),
+                                       scales=scales))
         if want_local:
-            local_parts.append(_per_pod(
-                lambda a, i, s: decode(a, i, s, m), q, idx, scales))
-        chunks.append(ChunkPayload(q=q, idx=idx.astype(jnp.uint16),
-                                   scales=scales))
-    local = jnp.concatenate(local_parts, axis=1) if want_local else None
+            with jax.named_scope("sync_ef"):
+                local_parts.append(_per_pod(
+                    lambda a, i, s: decode(a, i, s, m), q, idx, scales))
+    with jax.named_scope("sync_ef"):
+        local = (jnp.concatenate(local_parts, axis=1) if want_local
+                 else None)
     return tuple(chunks), local
 
 
@@ -880,8 +884,9 @@ class InlineRingShip:
                     shift: int, payload_mb: float = 0.0
                     ) -> Tuple[ChunkPayload, ...]:
         del name, payload_mb
-        return tuple(ChunkPayload(*(jnp.roll(p, shift, axis=0) for p in c))
-                     for c in chunks)
+        with jax.named_scope("sync_ring"):
+            return tuple(ChunkPayload(*(jnp.roll(p, shift, axis=0)
+                                        for p in c)) for c in chunks)
 
 
 _INLINE_RING = InlineRingShip()
@@ -904,12 +909,13 @@ def prepare_codec_sync(cfg: SyncConfig, state: SyncState) -> SyncPayloads:
     ``apply_sync`` composes this with a ship and :func:`finish_codec_sync`,
     and the trainer's host-seam path runs the three stages as separate
     dispatches so a real transport can time each bucket's transfer."""
-    denom = jnp.maximum(state.steps_since_sync, 1).astype(jnp.float32)
-    avg = jax.tree.map(lambda b: b / denom, state.ga_buffer)
-    layout = bucket_layout(cfg, avg)
-    flat = _pack_stacked(avg, layout)
-    if cfg.error_feedback:
-        flat = flat + state.ef_residual
+    with jax.named_scope("sync_encode"):
+        denom = jnp.maximum(state.steps_since_sync, 1).astype(jnp.float32)
+        avg = jax.tree.map(lambda b: b / denom, state.ga_buffer)
+        layout = bucket_layout(cfg, avg)
+        flat = _pack_stacked(avg, layout)
+        if cfg.error_feedback:
+            flat = flat + state.ef_residual
     chunks: Dict[str, Tuple[ChunkPayload, ...]] = {}
     local_parts = []
     for g, name in enumerate(layout.names):
@@ -922,8 +928,9 @@ def prepare_codec_sync(cfg: SyncConfig, state: SyncState) -> SyncPayloads:
         chunks[name] = bchunks
         if cfg.error_feedback:
             local_parts.append(local)
-    local = (jnp.concatenate(local_parts, axis=1) if local_parts
-             else (flat[:, :0] if cfg.error_feedback else None))
+    with jax.named_scope("sync_ef"):
+        local = (jnp.concatenate(local_parts, axis=1) if local_parts
+                 else (flat[:, :0] if cfg.error_feedback else None))
     return SyncPayloads(flat=flat, local=local, chunks=chunks)
 
 
@@ -996,14 +1003,15 @@ def finish_codec_sync(cfg: SyncConfig, params: Pytree, state: SyncState,
     spurious ef-guard trip."""
     layout = bucket_layout(cfg, state.ga_buffer)
     peer_parts = []
-    for g, name in enumerate(layout.names):
-        size = layout.sizes[g]
-        if size == 0:
-            peer_parts.append(payloads.flat[:, :0])
-            continue
-        peer_parts.append(_decode_bucket(cfg.for_bucket(name),
-                                         shipped[name], size))
-    peer_flat = jnp.concatenate(peer_parts, axis=1)
+    with jax.named_scope("sync_apply"):
+        for g, name in enumerate(layout.names):
+            size = layout.sizes[g]
+            if size == 0:
+                peer_parts.append(payloads.flat[:, :0])
+                continue
+            peer_parts.append(_decode_bucket(cfg.for_bucket(name),
+                                             shipped[name], size))
+        peer_flat = jnp.concatenate(peer_parts, axis=1)
     return _finish_from_peer(cfg, params, state, payloads.flat,
                              payloads.local, peer_flat, layout, lr, alive)
 
@@ -1020,38 +1028,43 @@ def _finish_from_peer(cfg: SyncConfig, params: Pytree, state: SyncState,
     full-round one on the plain path, the spliced prefix+tail one on the
     streaming retune path."""
     applied = delivered = None
-    if alive is not None:
-        alive = jnp.asarray(alive, jnp.float32)
-        # receiver p applies iff p and its ring sender (p - shift) are alive
-        applied = alive * jnp.roll(alive, cfg.peer_shift)
-        # sender p's message arrived iff p and its receiver (p + shift) are
-        delivered = alive * jnp.roll(alive, -cfg.peer_shift)
-        peer_flat = peer_flat * applied[:, None]
-    peer = _unpack_stacked(peer_flat, state.ga_buffer, layout)
-    # per-pod, per-bucket message norms — with EF also the residual norms;
-    # their ratio is the convergence signal the adaptive controllers guard
-    # on (a bucket's residual growing toward its message norm means that
-    # bucket's tier is dropping more than EF can recover per interval)
-    msg_norm = _bucket_norms(flat, layout)
+    with jax.named_scope("sync_apply"):
+        if alive is not None:
+            alive = jnp.asarray(alive, jnp.float32)
+            # receiver p applies iff p and its ring sender (p - shift) are alive
+            applied = alive * jnp.roll(alive, cfg.peer_shift)
+            # sender p's message arrived iff p and its receiver (p + shift) are
+            delivered = alive * jnp.roll(alive, -cfg.peer_shift)
+            peer_flat = peer_flat * applied[:, None]
+        peer = _unpack_stacked(peer_flat, state.ga_buffer, layout)
+        # per-pod, per-bucket message norms — with EF also the residual
+        # norms; their ratio is the convergence signal the adaptive
+        # controllers guard on (a bucket's residual growing toward its
+        # message norm means that bucket's tier is dropping more than EF
+        # can recover per interval)
+        msg_norm = _bucket_norms(flat, layout)
     new_resid, resid_norm = state.ef_residual, state.resid_norm
     if cfg.error_feedback:
-        new_resid = flat - local
+        with jax.named_scope("sync_ef"):
+            new_resid = flat - local
+            if delivered is not None:
+                new_resid = jnp.where(delivered[:, None] > 0, new_resid,
+                                      flat)
+            resid_norm = _bucket_norms(new_resid, layout)
+    with jax.named_scope("sync_apply"):
         if delivered is not None:
-            new_resid = jnp.where(delivered[:, None] > 0, new_resid, flat)
-        resid_norm = _bucket_norms(new_resid, layout)
-    if delivered is not None:
-        msg_norm = msg_norm * delivered[:, None]
-        resid_norm = resid_norm * delivered[:, None]
-    scale = jnp.asarray(lr, jnp.float32) * cfg.ga_lr_scale
-    params = jax.tree.map(
-        lambda p, g: (p.astype(jnp.float32) - scale * g).astype(p.dtype),
-        params, peer)
-    buf = jax.tree.map(jnp.zeros_like, state.ga_buffer)
+            msg_norm = msg_norm * delivered[:, None]
+            resid_norm = resid_norm * delivered[:, None]
+        scale = jnp.asarray(lr, jnp.float32) * cfg.ga_lr_scale
+        params = jax.tree.map(
+            lambda p, g: (p.astype(jnp.float32) - scale * g).astype(p.dtype),
+            params, peer)
+        buf = jax.tree.map(jnp.zeros_like, state.ga_buffer)
+        tier = jnp.asarray(cfg.bucket_tiers, jnp.int32)
     zero = state._replace(steps_since_sync=jnp.zeros((), jnp.int32))
     return params, zero._replace(ga_buffer=buf, ef_residual=new_resid,
-                                 tier=jnp.asarray(cfg.bucket_tiers,
-                                                  jnp.int32),
-                                 msg_norm=msg_norm, resid_norm=resid_norm)
+                                 tier=tier, msg_norm=msg_norm,
+                                 resid_norm=resid_norm)
 
 
 # ----------------------------------------------- streaming mid-round retune
@@ -1190,19 +1203,25 @@ def _ship_ring(cfg: SyncConfig, tree: Pytree) -> Pytree:
             nch = flat.shape[1] // chunk
             k = max(1, int(chunk * cfg.compress_topk))
             f3 = flat.reshape(n_pods, nch, chunk)
-            vals, idx = jax.vmap(jax.vmap(
-                lambda f: kops.topk_compress(f, k)))(f3)
-            vals = jnp.roll(vals, cfg.peer_shift, axis=0)
-            idx = jnp.roll(idx, cfg.peer_shift, axis=0)
-            dense = jax.vmap(jax.vmap(
-                lambda v, i: kops.topk_decompress(v, i, chunk)))(vals, idx)
+            with jax.named_scope("sync_encode"):
+                vals, idx = jax.vmap(jax.vmap(
+                    lambda f: kops.topk_compress(f, k)))(f3)
+            with jax.named_scope("sync_ring"):
+                vals = jnp.roll(vals, cfg.peer_shift, axis=0)
+                idx = jnp.roll(idx, cfg.peer_shift, axis=0)
+            with jax.named_scope("sync_apply"):
+                dense = jax.vmap(jax.vmap(
+                    lambda v, i: kops.topk_decompress(v, i, chunk)))(vals,
+                                                                     idx)
             dense = dense.reshape(n_pods, -1)
             if pad:
                 dense = dense[:, :numel]
             return dense.reshape(x.shape)
 
         return jax.tree.map(ship, tree)
-    return jax.tree.map(lambda x: jnp.roll(x, cfg.peer_shift, axis=0), tree)
+    with jax.named_scope("sync_ring"):
+        return jax.tree.map(lambda x: jnp.roll(x, cfg.peer_shift, axis=0),
+                            tree)
 
 
 def apply_sync(cfg: SyncConfig, params: Pytree, state: SyncState,
@@ -1237,17 +1256,20 @@ def apply_sync(cfg: SyncConfig, params: Pytree, state: SyncState,
                                          wire)
             return finish_codec_sync(cfg, params, state, payloads, shipped,
                                      lr)
-        denom = jnp.maximum(state.steps_since_sync, 1).astype(jnp.float32)
-        avg = jax.tree.map(lambda b: b / denom, state.ga_buffer)
+        with jax.named_scope("sync_encode"):
+            denom = jnp.maximum(state.steps_since_sync,
+                                1).astype(jnp.float32)
+            avg = jax.tree.map(lambda b: b / denom, state.ga_buffer)
         peer = _ship_ring(cfg, avg)
-        scale = jnp.asarray(lr, jnp.float32) * cfg.ga_lr_scale
-        params = jax.tree.map(
-            lambda p, g: (p.astype(jnp.float32) - scale * g).astype(p.dtype),
-            params, peer)
-        buf = jax.tree.map(jnp.zeros_like, state.ga_buffer)
-        return params, zero._replace(ga_buffer=buf,
-                                     tier=jnp.asarray(cfg.bucket_tiers,
-                                                      jnp.int32))
+        with jax.named_scope("sync_apply"):
+            scale = jnp.asarray(lr, jnp.float32) * cfg.ga_lr_scale
+            params = jax.tree.map(
+                lambda p, g: (p.astype(jnp.float32)
+                              - scale * g).astype(p.dtype),
+                params, peer)
+            buf = jax.tree.map(jnp.zeros_like, state.ga_buffer)
+            tier = jnp.asarray(cfg.bucket_tiers, jnp.int32)
+        return params, zero._replace(ga_buffer=buf, tier=tier)
 
     if cfg.strategy == "asp":
         # Gaia-style Approximate Synchronous Parallel: ship only parameter
@@ -1257,38 +1279,45 @@ def apply_sync(cfg: SyncConfig, params: Pytree, state: SyncState,
         # in place (params themselves carry them).
         eps = 1e-8
         ref = state.ga_buffer
-        delta = jax.tree.map(
-            lambda p, r: p.astype(jnp.float32) - r, params, ref)
-        sig = jax.tree.map(
-            lambda d, r: jnp.abs(d) > cfg.asp_threshold * (jnp.abs(r) + eps),
-            delta, ref)
-        shipped = jax.tree.map(
-            lambda d, m: jnp.where(m, d, 0.0), delta, sig)
-        n_sig = sum(jnp.sum(m) for m in jax.tree.leaves(sig))
-        n_tot = sum(m.size for m in jax.tree.leaves(sig))
-        frac = n_sig.astype(jnp.float32) / n_tot
+        with jax.named_scope("sync_encode"):
+            delta = jax.tree.map(
+                lambda p, r: p.astype(jnp.float32) - r, params, ref)
+            sig = jax.tree.map(
+                lambda d, r: jnp.abs(d) > cfg.asp_threshold * (jnp.abs(r)
+                                                              + eps),
+                delta, ref)
+            shipped = jax.tree.map(
+                lambda d, m: jnp.where(m, d, 0.0), delta, sig)
+            n_sig = sum(jnp.sum(m) for m in jax.tree.leaves(sig))
+            n_tot = sum(m.size for m in jax.tree.leaves(sig))
+            frac = n_sig.astype(jnp.float32) / n_tot
         peer = _ship_ring(cfg, shipped)
-        params = jax.tree.map(
-            lambda p, q: (p.astype(jnp.float32) + 0.5 * q).astype(p.dtype),
-            params, peer)
-        new_ref = jax.tree.map(lambda p: p.astype(jnp.float32), params)
+        with jax.named_scope("sync_apply"):
+            params = jax.tree.map(
+                lambda p, q: (p.astype(jnp.float32)
+                              + 0.5 * q).astype(p.dtype),
+                params, peer)
+            new_ref = jax.tree.map(lambda p: p.astype(jnp.float32), params)
         return params, zero._replace(ga_buffer=new_ref,
                                      significant_frac=frac)
 
     if cfg.strategy == "ama":
         peer = _ship_ring(cfg, params)
-        params = jax.tree.map(
-            lambda p, q: ((p.astype(jnp.float32) + q.astype(jnp.float32)) * 0.5
-                          ).astype(p.dtype),
-            params, peer)
+        with jax.named_scope("sync_apply"):
+            params = jax.tree.map(
+                lambda p, q: ((p.astype(jnp.float32)
+                               + q.astype(jnp.float32)) * 0.5
+                              ).astype(p.dtype),
+                params, peer)
         return params, zero
 
     # sma — barrier global average
-    params = jax.tree.map(
-        lambda p: jnp.broadcast_to(
-            jnp.mean(p.astype(jnp.float32), axis=0, keepdims=True),
-            p.shape).astype(p.dtype),
-        params)
+    with jax.named_scope("sync_apply"):
+        params = jax.tree.map(
+            lambda p: jnp.broadcast_to(
+                jnp.mean(p.astype(jnp.float32), axis=0, keepdims=True),
+                p.shape).astype(p.dtype),
+            params)
     return params, zero
 
 

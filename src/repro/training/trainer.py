@@ -38,8 +38,7 @@ from repro.core.sync import (SyncConfig, SyncState, _chunk_widths,
                              retune_sync_state, ship_sync_payloads,
                              shrink_pods, traffic_per_step_mb)
 from repro.optim.optimizers import (Optimizer, clip_by_global_norm,
-                                    constant_schedule, get_optimizer,
-                                    global_norm)
+                                    constant_schedule, get_optimizer)
 
 Pytree = Any
 
@@ -171,22 +170,29 @@ class Trainer:
                          ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
         lr = self.schedule(state.step)
 
-        grad_fn = jax.value_and_grad(self.loss_fn, has_aux=True)
+        def forward(params, batch):
+            # inside the differentiated function, so the backward pass's
+            # ops carry ``transpose(jvp(train_forward))`` in their names
+            with jax.named_scope("train_forward"):
+                return self.loss_fn(params, batch)
+
+        grad_fn = jax.value_and_grad(forward, has_aux=True)
         (loss, metrics), grads = jax.vmap(grad_fn)(state.params, batch)
 
-        if self.cfg.clip_norm > 0:
-            grads = jax.vmap(
-                lambda g: clip_by_global_norm(g, self.cfg.clip_norm))(grads)
+        with jax.named_scope("train_update"):
+            if self.cfg.clip_norm > 0:
+                grads = jax.vmap(lambda g: clip_by_global_norm(
+                    g, self.cfg.clip_norm))(grads)
 
-        grads, sync_state = on_step_gradients(self.cfg.sync, grads,
-                                              state.sync_state)
+            grads, sync_state = on_step_gradients(self.cfg.sync, grads,
+                                                  state.sync_state)
 
-        new_params, new_opt = jax.vmap(
-            self.optimizer.update, in_axes=(0, 0, 0, None)
-        )(grads, state.opt_state, state.params, lr)
+            new_params, new_opt = jax.vmap(
+                self.optimizer.update, in_axes=(0, 0, 0, None)
+            )(grads, state.opt_state, state.params, lr)
 
         out_metrics = {"loss": jnp.mean(loss), "loss_per_pod": loss,
-                       "grad_norm": jax.vmap(global_norm)(grads), "lr": lr}
+                       "lr": lr}
         for k, v in metrics.items():
             if k not in ("loss",):
                 out_metrics[k] = jnp.mean(v)
@@ -384,13 +390,52 @@ class Trainer:
         return self._finish_sync(state, payloads, shipped)
 
     def train_step(self, state, batch):
-        return self._train_step(state, batch)
+        with jax.profiler.TraceAnnotation("repro.train_step"):
+            return self._train_step(state, batch)
 
     def sync_step_hlo(self, state: TrainState) -> str:
         """Optimized HLO of the in-graph sync step compiled for ``state``:
         what one sync round runs (its Pallas kernels appear as
         ``tpu_custom_call``s)."""
         return self._sync_step.lower(state).compile().as_text()
+
+    def program_hlo(self, state: TrainState, batch: Pytree
+                    ) -> Dict[str, Tuple[str, ...]]:
+        """Optimized HLO texts of the train-step and in-graph sync-step
+        programs the step loop runs from ``state`` on batches like
+        ``batch``, keyed by HLO module name.  Each instruction's ``op_name``
+        metadata carries the ``jax.named_scope`` it was traced under
+        (``train_forward``, ``train_update``, ``sync_encode``, ``sync_ef``,
+        ``sync_ring``, ``sync_apply``), which is how a profiler trace's op
+        events are read by phase.
+
+        A program compiled for more than one input signature in the loop
+        has one text per variant: on a pod mesh the train step after a
+        sync round is another executable than the one after a train step.
+        The variants are found from the compiled programs' output shapes
+        and shardings; nothing runs."""
+        texts: Dict[str, List[str]] = {}
+
+        def compiled(fn, *args):
+            c = fn.lower(*args).compile()
+            text = c.as_text()
+            got = texts.setdefault(text.split(None, 2)[1].rstrip(","), [])
+            if text not in got:
+                got.append(text)
+            return c.out_info
+
+        seen, todo = set(), [state]
+        while todo:
+            st = todo.pop()
+            sig = tuple((x.shape, str(x.dtype), str(x.sharding),
+                         bool(getattr(x, "weak_type", False)))
+                        for x in jax.tree.leaves(st))
+            if sig in seen:
+                continue
+            seen.add(sig)
+            after_train = compiled(self._train_step, st, batch)[0]
+            todo += [after_train, compiled(self._sync_step, after_train)]
+        return {name: tuple(t) for name, t in texts.items()}
 
     # ------------------------------------------------------ elasticity
     def reconfigure(self, state: TrainState, n_pods: int,
@@ -460,38 +505,41 @@ class Trainer:
 
     def maybe_sync(self, state: TrainState, host_step: int,
                    model_mb: float = 0.0) -> TrainState:
-        if self.cfg.n_pods > 1:
-            # WAN transfers per sync round: the flat ring's count is one
-            # per pod; a hierarchical transport exposes its compiled
-            # schedule's count (tree over R regions: 2(R-1); auxiliary
-            # routes pay both hops) — same multiplier cost.adaptive_traffic_mb
-            # bills and the DES charges
-            legs = getattr(self.transport, "wan_transfers_per_round", None)
-            self.traffic_mb += traffic_per_step_mb(
-                self.cfg.sync, model_mb,
-                bucket_weights=self.bucket_weights(state)) * (
-                    legs if legs is not None else self.cfg.n_pods)
-        if is_sync_step(self.cfg.sync, host_step) and self.cfg.n_pods > 1:
-            # fault-aware transports arm their plan per round (which pods
-            # are dead, which transfers will need retries) before shipping
-            begin = getattr(self.transport, "begin_round", None)
-            if begin is not None:
-                begin(host_step)
-            if self._can_stream():
-                streamed = self._stream_sync(state, host_step)
-                if streamed is not None:
-                    # the streaming round already billed itself
-                    # (end_stream_round IS this round's barrier)
-                    return streamed
-            if self._host_seam and self.cfg.sync.uses_codec:
-                state = self._host_sync(state)
-            else:
-                state = self._sync_step(state)
-            if self.transport is not None:
-                # round barrier: bill (sim) or flush (mesh) this round's
-                # transfers into the transport's records + measured probe
-                self.transport.on_sync(self.wire_mb(state), step=host_step)
-        return state
+        with jax.profiler.TraceAnnotation("repro.maybe_sync"):
+            if self.cfg.n_pods > 1:
+                # WAN transfers per sync round: the flat ring's count is one
+                # per pod; a hierarchical transport exposes its compiled
+                # schedule's count (tree over R regions: 2(R-1); auxiliary
+                # routes pay both hops) — same multiplier
+                # cost.adaptive_traffic_mb bills and the DES charges
+                legs = getattr(self.transport, "wan_transfers_per_round",
+                               None)
+                self.traffic_mb += traffic_per_step_mb(
+                    self.cfg.sync, model_mb,
+                    bucket_weights=self.bucket_weights(state)) * (
+                        legs if legs is not None else self.cfg.n_pods)
+            if is_sync_step(self.cfg.sync, host_step) and self.cfg.n_pods > 1:
+                # fault-aware transports arm their plan per round (which pods
+                # are dead, which transfers will need retries) before shipping
+                begin = getattr(self.transport, "begin_round", None)
+                if begin is not None:
+                    begin(host_step)
+                with jax.profiler.TraceAnnotation("repro.sync_round"):
+                    if self._can_stream():
+                        streamed = self._stream_sync(state, host_step)
+                        if streamed is not None:
+                            # the streaming round already billed itself
+                            # (end_stream_round IS this round's barrier)
+                            return streamed
+                    if self._host_seam and self.cfg.sync.uses_codec:
+                        state = self._host_sync(state)
+                    else:
+                        state = self._sync_step(state)
+                if self.transport is not None:
+                    # round barrier: bill (sim) or flush (mesh) this round's
+                    # transfers into the transport's records + measured probe
+                    self.transport.on_sync(self.wire_mb(state), step=host_step)
+            return state
 
     # --------------------------------------------------------------- loop
     def fit(self, state: TrainState, batches: Callable[[int], Pytree],
