@@ -18,23 +18,49 @@ Selection algorithm (threshold refinement, no O(k) serialization):
 3. Build the k-th-largest key threshold bit-by-bit: 16 branch-free rounds of
    ``count(keys >= candidate)``, each a fully vectorized compare+reduce over
    the whole tile.  Work is O(16 * block) independent of k.
-4. Select ``keys > T`` plus the first (by index) ties at ``T``; exact ranks
-   come from a prefix sum done as log-step lane rotations and adds —
-   again vectorized, never serialized.
-5. Compact the winners with a one-hot dot product (the TPU-native scatter:
-   MXU contraction instead of unsupported vector scatters).  Each one-hot
-   column has exactly one nonzero and the dot runs at ``HIGHEST``
-   precision, so the gathered fp32 values and the local indices
-   (< block <= 2^16) come out exact.
-6. Quantize the selected values to int8 against a per-block scale
-   ``max|x| / 127`` — fused into the same kernel, so the fp32 payload never
+4. Select ``keys > T`` plus the first (by index) ties at ``T``.  Ranks in
+   index order come from prefix sums done on the MXU: a 0/1 tile times a
+   triangular 0/1 matrix counts along each 128-lane row, times a ones
+   matrix gives the row totals, and a strictly lower-triangular matrix
+   sums the totals of the rows above.  Each operand is 0, 1 or a row total
+   <= 128, so the bf16 products and their f32 sums are exact.
+5. Quantize the whole tile against the per-block scale (``max|x| / 127``
+   for int8): the same expression on the same value as quantizing only the
+   winners, so the codes are bit-identical, and the fp32 payload never
    round-trips through HBM.
+6. Gather the winners with a one-hot factored into row and lane.  A
+   block's winners fill the slots in index order, so slot ``j`` lies in
+   row ``r`` iff ``start_r <= j < start_r + count_r`` (the row's winner
+   count and the count before it).  That (rows, k) row one-hot fetches
+   each slot's whole row of codes and slot keys (slot mod 256, or 256 for
+   a loser) in one matmul; within a row the winners hold fewer than 256
+   consecutive slots, so the one lane whose key is ``j mod 256`` gives the
+   slot's code and lane: ``idx_j = 128 * row_j + lane_j``.  Work per block
+   is O(block + 128 * k), not O(block * k).
 
-Tile geometry: each grid step processes ``ROWS`` = 8 independent blocks as
-a 2D (8, block) tile — one fp32 sublane tile, the VPU-natural layout; a
-block row is padded to whole 128-lane vregs.  The selection math batches
-over the rows; the one-hot gather then walks the tile one block row (and,
-at high k, one lane chunk) at a time so its tile stays within VMEM.
+Decoding is the same factorization in transpose: ``(row one-hot x code) @
+lane one-hot`` puts each code at its (row, lane) in one matmul, and the
+scale is applied after on the VPU, as the oracle's ``code * scale``.  The
+output matches the oracle's bits, signed zeros included: a zero sum (a
+product with a zero operand keeps the other's sign) becomes the oracle's
+``+0`` fill, and fp8's ``-0`` code is put back by a second 0/1 matmul.
+
+Every MXU product in both kernels is exact at the default precision: the
+operands are 0 or 1, int8 container codes (integers of magnitude <= 128),
+e4m3 values (3 mantissa bits, exponents well inside bf16's) or slot keys
+in [0, 256], all of which bf16 holds; and each output sums at most one
+nonzero product, because a block's winners sit at distinct positions and
+fill distinct slots.
+
+Tile geometry: a block of ``block`` elements is a (rows, 128) tile, rows =
+ceil(block / 128), its tail padded with zeros; for a block that is a
+multiple of 128 this is the flat message's own tiled order, so the
+wrapper's (nb, rows, 128) view needs no transpose.  Each grid step takes
+a group of whole blocks (``_ENCODE_GROUP``, ``_DECODE_GROUP``).  The
+threshold search reads them block-major (one block per sublane row, so
+each round's counts are lane reductions shared by the group); everything
+after works on the (group, rows, 128) tiles, and the gather and scatter
+walk the slots 128 at a time, so no value of a step grows with k.
 
 Wire format per block of ``block`` elements: ``k_block`` encoded values +
 ``k_block`` block-local indices (< 2^16, i.e. u16 on the wire; int32 in
@@ -66,7 +92,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 # keep the top 16 of the 31 magnitude bits (sign bit of |x| is always 0):
 # bits 30..23 exponent, 22..15 top mantissa byte
@@ -85,14 +110,11 @@ INV_FP8_MAX = 1.0 / 448.0
 VALUE_DTYPES = ("int8", "fp8", "int4")  # the codec's precision ladder
 
 DEFAULT_BLOCK = 4096
-ROWS = 8                               # blocks per grid step: one f32 sublane tile
-
-# the (k_block, chunk) fp32 one-hot tile of one block row is the kernels'
-# VMEM high-water mark; the gather walks the block in lane chunks small
-# enough to keep it under budget at ANY compress fraction (the rows per
-# grid step stay at one sublane tile — chunking is semantics-free)
-_ONEHOT_BUDGET_BYTES = 2 << 20
-_LANES = 128
+_LANES = 128                           # a block row: one vreg's lanes
+# blocks per grid step, from a sweep of 8, 16 and 32 on a TPU v5e (block
+# 4096, k 82): encode was fastest at 32, decode at 8
+_ENCODE_GROUP = 32
+_DECODE_GROUP = 8
 
 
 def k_per_block(block: int, frac: float) -> int:
@@ -104,41 +126,21 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _onehot_chunk(lanes: int, k_block: int) -> int:
-    """Lane width of one gather step over a ``lanes``-wide block row: the
-    whole row, halved (staying a multiple of 128) while the one-hot tile
-    exceeds the budget."""
-    chunk = lanes
-    while chunk * _round_up(k_block, _LANES) * 4 > _ONEHOT_BUDGET_BYTES \
-            and chunk % (2 * _LANES) == 0:
-        chunk //= 2
-    return chunk
+def _block_threshold(x_ref, k_block: int):
+    """Per-block selection threshold over a (G, rows, 128) tile of G blocks.
 
-
-def _cumsum_lanes(v: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive prefix sum along the last axis: log-step roll-and-add
-    (Mosaic has no cumsum lowering; a lane rotate is native)."""
-    n = v.shape[-1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
-    shift = 1
-    while shift < n:
-        v = v + jnp.where(lane >= shift, pltpu.roll(v, shift, v.ndim - 1), 0)
-        shift *= 2
-    return v
-
-
-def _select_slots(x: jnp.ndarray, k_block: int):
-    """Exact block-local top-k selection over a (rows, block) tile.
-
-    Returns (slot int32 (rows, block): the winner's output slot, in index
-    order, or -1 for a loser; maxabs f32 (rows, 1)).  Selection key: |x|
-    truncated to KEY_MASK bits; ties broken by lowest index (matching
-    ``jax.lax.top_k``'s stable ordering in the oracle).
+    Returns (thresh int32 (G, 1): the k-th largest truncated key,
+    n_above int32 (G, 1): how many keys exceed it, maxabs f32 (G, 1)).
+    Works on the block-major view, each block one sublane row of
+    ``rows * 128`` lanes, so each refinement round's per-block counts are
+    lane reductions shared by G blocks.
     """
+    x = jnp.concatenate([x_ref[:, r, :] for r in range(x_ref.shape[1])],
+                        axis=1).astype(jnp.float32)
     mag = jnp.abs(x)
     bits = jax.lax.bitcast_convert_type(mag, jnp.int32) & KEY_MASK
 
-    # threshold refinement: per row, largest T with count(bits >= T) >=
+    # threshold refinement: per block, largest T with count(bits >= T) >=
     # k_block, built bit-by-bit over the 16 key bits — branch-free
     # compare+reduce on the full tile each round
     def refine(i, t):
@@ -148,57 +150,70 @@ def _select_slots(x: jnp.ndarray, k_block: int):
 
     thresh = jax.lax.fori_loop(
         0, _N_KEY_BITS, refine, jnp.zeros((x.shape[0], 1), jnp.int32))
-
-    above = bits > thresh
-    n_above = jnp.sum(above.astype(jnp.int32), axis=1, keepdims=True)
-    at = bits == thresh
-    # first (k_block - n_above) ties by index, exactly filling k_block
-    tie_rank = _cumsum_lanes(at.astype(jnp.int32)) - 1
-    mask = above | (at & (tie_rank < k_block - n_above))
-    pos = _cumsum_lanes(mask.astype(jnp.int32)) - 1       # slot, by index
-    return jnp.where(mask, pos, -1), jnp.max(mag, axis=1, keepdims=True)
+    n_above = jnp.sum((bits > thresh).astype(jnp.int32), axis=1,
+                      keepdims=True)
+    return thresh, n_above, jnp.max(mag, axis=1, keepdims=True)
 
 
-def _quantize(vals: jnp.ndarray, maxabs: jnp.ndarray, value_dtype: str
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-tier value encoding of a (rows, k_block) tile of selected values
-    against the (rows, 1) block maxima.
+def _row_scan(v: jnp.ndarray):
+    """Prefix sums in index order of a 0/1 f32 (G, rows, 128) tile.
 
-    Returns (q int8, scale f32 (rows, 1)).  ``q`` is always an int8
-    *container*: the int4 tier's [-7, 7] codes are nibble-packed by the
-    wrapper (packing is a pure bit shuffle, not kernel work), the fp8 tier
-    ships the e4m3 bit pattern bitcast to int8.  All three run identically
-    in the oracle — the expressions below are the bit-level spec.
-    """
+    Returns (incl: inclusive prefix along each row, tot: the row's total,
+    before: the totals of the rows above it), each f32 (G, rows, 128), the
+    last two constant along lanes.  All three are MXU products whose
+    operands are 0, 1 or a row total <= 128, so bf16 holds every operand
+    exactly and the f32 accumulation is exact."""
+    g, rows, lanes = v.shape
+    li = jax.lax.broadcasted_iota(jnp.int32, (lanes, 2 * lanes), 0)
+    lo = jax.lax.broadcasted_iota(jnp.int32, (lanes, 2 * lanes), 1)
+    scan = ((li <= lo) | (lo >= lanes)).astype(jnp.bfloat16)  # [tri | ones]
+    cu = jnp.einsum("grl,lm->grm", v.astype(jnp.bfloat16), scan,
+                    preferred_element_type=jnp.float32)
+    incl, tot = cu[..., :lanes], cu[..., lanes:]
+    if rows == 1:
+        return incl, tot, jnp.zeros_like(tot)
+    ri = jax.lax.broadcasted_iota(jnp.int32, (g, rows, rows), 1)
+    si = jax.lax.broadcasted_iota(jnp.int32, (g, rows, rows), 2)
+    before = jnp.einsum("grs,gsl->grl", (si < ri).astype(jnp.bfloat16),
+                        tot.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    return incl, tot, before
+
+
+def _scale(maxabs: jnp.ndarray, value_dtype: str) -> jnp.ndarray:
+    """Per-block scale of the tier: ``max|x|`` over the largest code."""
+    inv = {"int8": INV_127, "int4": INV_7, "fp8": INV_FP8_MAX}
+    if value_dtype not in inv:
+        raise ValueError(f"unknown value_dtype {value_dtype!r} "
+                         f"(expected one of {VALUE_DTYPES})")
+    return jnp.where(maxabs > 0, maxabs * jnp.float32(inv[value_dtype]), 1.0)
+
+
+def _codes(x: jnp.ndarray, scale: jnp.ndarray, value_dtype: str
+           ) -> jnp.ndarray:
+    """Per-tier value encoding of ``x`` against its block's ``scale``, as
+    the f32 value of the int8 *container* (an integer in [-128, 127]): the
+    int4 tier's [-7, 7] codes are nibble-packed by the wrapper (packing is a
+    pure bit shuffle, not kernel work), the fp8 tier ships the e4m3 bit
+    pattern bitcast to int8.  The oracle runs the same expressions — they
+    are the bit-level spec."""
     if value_dtype == "int8":
-        scale = jnp.where(maxabs > 0, maxabs * jnp.float32(INV_127), 1.0)
-        q = jnp.clip(jnp.round(vals / scale), -127.0, 127.0)
-        return q.astype(jnp.int8), scale
+        return jnp.clip(jnp.round(x / scale), -127.0, 127.0)
     if value_dtype == "int4":
-        scale = jnp.where(maxabs > 0, maxabs * jnp.float32(INV_7), 1.0)
-        q = jnp.clip(jnp.round(vals / scale), -7.0, 7.0)
-        return q.astype(jnp.int8), scale
-    if value_dtype == "fp8":
-        # map the block max onto e4m3's largest finite value, clip the 1-ulp
-        # overshoot the fp32 reciprocal can introduce, ship the bit pattern
-        scale = jnp.where(maxabs > 0, maxabs * jnp.float32(INV_FP8_MAX), 1.0)
-        f8 = jnp.clip(vals / scale, -FP8_MAX, FP8_MAX
-                      ).astype(jnp.float8_e4m3fn)
-        return jax.lax.bitcast_convert_type(f8, jnp.int8), scale
-    raise ValueError(f"unknown value_dtype {value_dtype!r} "
-                     f"(expected one of {VALUE_DTYPES})")
+        return jnp.clip(jnp.round(x / scale), -7.0, 7.0)
+    # fp8: map the block max onto e4m3's largest finite value, clip the
+    # 1-ulp overshoot the fp32 reciprocal can introduce, ship the bits
+    f8 = jnp.clip(x / scale, -FP8_MAX, FP8_MAX).astype(jnp.float8_e4m3fn)
+    return jax.lax.bitcast_convert_type(f8, jnp.int8).astype(jnp.float32)
 
 
-def _dequantize(q: jnp.ndarray, scales: jnp.ndarray, value_dtype: str
-                ) -> jnp.ndarray:
-    """Inverse of :func:`_quantize` ((rows, k) int8 container, (rows, 1)
-    scales -> f32)."""
+def _code_values(q: jnp.ndarray, value_dtype: str) -> jnp.ndarray:
+    """The value each int8 container code stands for, before its scale:
+    the integer itself (int8, unpacked int4) or the e4m3 number (fp8)."""
     if value_dtype == "fp8":
-        v = jax.lax.bitcast_convert_type(q, jnp.float8_e4m3fn
-                                         ).astype(jnp.float32)
-    else:                                   # int8 / (unpacked) int4 codes
-        v = q.astype(jnp.float32)
-    return v * scales
+        return jax.lax.bitcast_convert_type(q, jnp.float8_e4m3fn
+                                            ).astype(jnp.float32)
+    return q.astype(jnp.float32)
 
 
 def pack_nibbles(q: jnp.ndarray) -> jnp.ndarray:
@@ -223,75 +238,107 @@ def unpack_nibbles(p: jnp.ndarray, k: int) -> jnp.ndarray:
     return signed[..., :k].astype(jnp.int8)
 
 
-# the one-hot gathers are exact only if the MXU keeps every fp32 bit of
-# the gathered values and of the indices: at the TPU's default precision
-# fp32 operands may pass through bf16, which holds integers only to 256
-_EXACT = jax.lax.Precision.HIGHEST
-_NT = (((1,), (1,)), ((), ()))                          # a @ b.T
+# Each gather and scatter below is one bf16 MXU product with f32
+# accumulation at the default precision, exact because bf16 holds every
+# operand and each output sums at most one nonzero product (module
+# docstring).
 
 
-def _encode_kernel(x_ref, q_ref, idx_ref, scale_ref, slot_ref, vals_ref, *,
-                   k_block: int, chunk: int, value_dtype: str):
-    rows, lanes = x_ref.shape
-    slot, maxabs = _select_slots(x_ref[...].astype(jnp.float32), k_block)
-    slot_ref[...] = slot
+def _encode_kernel(x_ref, q_ref, idx_ref, scale_ref, *, k_block: int,
+                   value_dtype: str):
+    g, rows, lanes = x_ref.shape
+    thresh, n_above, maxabs = _block_threshold(x_ref, k_block)
+    x = x_ref[...].astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(jnp.abs(x), jnp.int32) & KEY_MASK
+    t = thresh.reshape(g, 1, 1)
 
-    # one-hot compaction, one block row and one lane chunk at a time: the
-    # (k_block, chunk) one-hot has exactly one 1 per winner, so the dot is
-    # an exact gather on the MXU.  Row 0 of the left operand carries the
-    # values, row 1 the lane indices
-    sub = jax.lax.broadcasted_iota(jnp.int32, (ROWS, chunk), 0)
-    slots = jax.lax.broadcasted_iota(jnp.int32, (k_block, chunk), 0)
-    out_row = jax.lax.broadcasted_iota(jnp.int32, (ROWS, k_block), 0)
-    for r in range(rows):
-        acc = jnp.zeros((ROWS, k_block), jnp.float32)
-        for c in range(0, lanes, chunk):
-            onehot = (slots == slot_ref[r:r + 1, c:c + chunk]
-                      ).astype(jnp.float32)
-            lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) + c
-            lhs = jnp.where(sub == 0, x_ref[r:r + 1, c:c + chunk]
-                            .astype(jnp.float32), lane.astype(jnp.float32))
-            acc = acc + jax.lax.dot_general(
-                lhs, onehot, _NT, precision=_EXACT,
-                preferred_element_type=jnp.float32)
-        vals_ref[r:r + 1, :] = jnp.sum(jnp.where(out_row == 0, acc, 0.0),
-                                       axis=0, keepdims=True)
-        idx_ref[r:r + 1, :] = jnp.sum(jnp.where(out_row == 1, acc, 0.0),
-                                      axis=0, keepdims=True
-                                      ).astype(jnp.int32)
+    # winners: keys above T plus the first (k_block - n_above) ties by index
+    at = bits == t
+    t_incl, _, t_before = _row_scan(at.astype(jnp.float32))
+    need = (k_block - n_above).astype(jnp.float32).reshape(g, 1, 1)
+    win = (bits > t) | (at & (t_before + t_incl <= need))
+    w_incl, w_tot, w_before = _row_scan(win.astype(jnp.float32))
 
-    q, scale = _quantize(vals_ref[...], maxabs, value_dtype)
-    q_ref[...] = q
+    scale = _scale(maxabs, value_dtype)
+    codes = _codes(x, scale.reshape(g, 1, 1), value_dtype)
+    # per position: its slot mod 256 (256 for a loser) and its code; a
+    # row's winners fill consecutive slots, fewer than 256 of them, so the
+    # key names one lane of the row
+    slot = (w_before + w_incl).astype(jnp.int32) - 1
+    key = jnp.where(win, (slot & 255).astype(jnp.float32), 256.0)
+    rhs = jnp.concatenate([key, codes], axis=2).astype(jnp.bfloat16)
+    start, count = w_before[..., :1], w_tot[..., :1]
+
+    # factored gather, 128 slots at a time: row one-hot a_t[r, j] (slot j
+    # lies in row r) fetches the row's keys and codes, then the lane whose
+    # key is j mod 256 gives slot j's lane and code
+    for c0 in range(0, k_block, lanes):
+        kc = min(lanes, k_block - c0)
+        j = jax.lax.broadcasted_iota(jnp.int32, (g, rows, kc), 2) + c0
+        a_t = (j >= start) & (j < start + count)
+        got = jnp.einsum("grc,grk->gck", rhs, a_t.astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32)  # (g, 2L, kc)
+        jj = jax.lax.broadcasted_iota(jnp.int32, (g, lanes, kc), 2) + c0
+        hit = got[:, :lanes, :] == (jj & 255).astype(jnp.float32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (g, lanes, kc), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (g, rows, kc), 1)
+        code = jnp.sum(jnp.where(hit, got[:, lanes:, :], 0.0), axis=1)
+        col = jnp.sum(jnp.where(hit, lane, 0), axis=1)
+        r_j = jnp.sum(jnp.where(a_t, row, 0), axis=1)
+        q_ref[:, c0:c0 + kc] = code.astype(jnp.int8)
+        idx_ref[:, c0:c0 + kc] = r_j * lanes + col
     scale_ref[...] = scale
 
 
-def _decode_kernel(q_ref, idx_ref, scale_ref, out_ref, *, chunk: int,
-                   value_dtype: str):
-    rows, lanes = out_ref.shape
+def _decode_kernel(q_ref, idx_ref, scale_ref, out_ref, *, value_dtype: str):
+    g, rows, lanes = out_ref.shape
     k_block = idx_ref.shape[1]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, k_block), 0)
-    # transpose of the encode compaction: one nonzero per column -> exact
-    for r in range(rows):
-        v = _dequantize(q_ref[r:r + 1, :], scale_ref[r:r + 1, :], value_dtype)
-        idx = idx_ref[r:r + 1, :]
-        for c in range(0, lanes, chunk):
-            onehot = (cols + c == idx).astype(jnp.float32)
-            out_ref[r:r + 1, c:c + chunk] = jax.lax.dot_general(
-                v, onehot, _NT, precision=_EXACT,
-                preferred_element_type=jnp.float32)
+    code = _code_values(q_ref[...], value_dtype)
+    if value_dtype == "fp8":
+        minus_zero = (q_ref[...].astype(jnp.int32) == -128
+                      ).astype(jnp.float32)
+    idx = idx_ref[...]
+    # factored scatter, 128 slots at a time: (row one-hot x code) @ lane
+    # one-hot puts each code at its (row, lane); the scale is applied after,
+    # on the VPU, as the oracle's code * scale.  A product with a zero
+    # operand keeps the other's sign, so a zero sum is made the oracle's +0
+    # fill, and an fp8 -0 code (bits 0x80) is put back where it lands
+    dense = jnp.zeros((g, rows, lanes), jnp.float32)
+    neg_zero = dense
+    for c0 in range(0, k_block, lanes):
+        kc = min(lanes, k_block - c0)
+        i = idx[:, c0:c0 + kc].reshape(g, 1, kc)
+        v = code[:, c0:c0 + kc].reshape(g, 1, kc)
+        row = jax.lax.broadcasted_iota(jnp.int32, (g, rows, kc), 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (g, lanes, kc), 1)
+        in_row = row == (i >> 7)
+        a = jnp.where(in_row, v, 0.0).astype(jnp.bfloat16)
+        b_t = (lane == (i & (lanes - 1))).astype(jnp.bfloat16)
+        dense = dense + jnp.einsum("grk,glk->grl", a, b_t,
+                                   preferred_element_type=jnp.float32)
+        if value_dtype == "fp8":
+            mz = minus_zero[:, c0:c0 + kc].reshape(g, 1, kc)
+            z = jnp.where(in_row, mz, 0.0).astype(jnp.bfloat16)
+            neg_zero = neg_zero + jnp.einsum(
+                "grk,glk->grl", z, b_t, preferred_element_type=jnp.float32)
+    out = jnp.where(dense == 0.0, 0.0, dense) * scale_ref[...].reshape(g, 1, 1)
+    if value_dtype == "fp8":
+        out = jnp.where(neg_zero > 0.0, -0.0, out)
+    out_ref[...] = out
 
 
-def _geometry(n: int, block: int, k_block: int
+def _geometry(n: int, block: int, k_block: int, group: int
               ) -> Tuple[int, int, int, int, int]:
-    """(block, k_block, lanes, nb_real, nb_padded): pad n up to whole
-    (ROWS x block) tiles and each block row up to whole 128-lane vregs.
-    Padded blocks and lanes are all-zero and sliced off the outputs; a
-    zero pad lane never displaces a real element (ties go to the lowest
-    index, and a real block always has at least ``k_block`` elements)."""
+    """(block, k_block, rows, nb_real, nb_padded): each block a (rows, 128)
+    tile, its tail padded to whole lane rows, and n padded to whole groups
+    of ``group`` blocks.  Padded blocks and lanes are all-zero and sliced
+    off the outputs; a zero pad lane never displaces a real element (ties
+    go to the lowest index, and a real block always has at least
+    ``k_block`` elements)."""
     block = min(block, n)
     nb = -(-n // block)
-    return (block, min(k_block, block), _round_up(block, _LANES), nb,
-            _round_up(nb, ROWS))
+    return (block, min(k_block, block), -(-block // _LANES), nb,
+            _round_up(nb, group))
 
 
 @functools.partial(jax.jit,
@@ -306,27 +353,25 @@ def wan_encode_pallas(
     int8/fp8 (fp8 ships its bit pattern), uint8 (nb*ceil(k_block/2),)
     nibble-packed for int4."""
     n = x.shape[0]
-    block, k_block, lanes, nb, nb_pad = _geometry(n, block, k_block)
+    g = _ENCODE_GROUP
+    block, k_block, rows, nb, nb_pad = _geometry(n, block, k_block, g)
     xp = jnp.pad(x, (0, nb_pad * block - n)).reshape(nb_pad, block)
-    xp = jnp.pad(xp, ((0, 0), (0, lanes - block)))
+    xp = jnp.pad(xp, ((0, 0), (0, rows * _LANES - block)))
     row = lambda b: (b, 0)                              # noqa: E731
 
     q, idx, scales = pl.pallas_call(
         functools.partial(_encode_kernel, k_block=k_block,
-                          chunk=_onehot_chunk(lanes, k_block),
                           value_dtype=value_dtype),
-        grid=(nb_pad // ROWS,),
-        in_specs=[pl.BlockSpec((ROWS, lanes), row)],
-        out_specs=[pl.BlockSpec((ROWS, k_block), row),
-                   pl.BlockSpec((ROWS, k_block), row),
-                   pl.BlockSpec((ROWS, 1), row)],
+        grid=(nb_pad // g,),
+        in_specs=[pl.BlockSpec((g, rows, _LANES), lambda b: (b, 0, 0))],
+        out_specs=[pl.BlockSpec((g, k_block), row),
+                   pl.BlockSpec((g, k_block), row),
+                   pl.BlockSpec((g, 1), row)],
         out_shape=[jax.ShapeDtypeStruct((nb_pad, k_block), jnp.int8),
                    jax.ShapeDtypeStruct((nb_pad, k_block), jnp.int32),
                    jax.ShapeDtypeStruct((nb_pad, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((ROWS, lanes), jnp.int32),
-                        pltpu.VMEM((ROWS, k_block), jnp.float32)],
         interpret=interpret,
-    )(xp)
+    )(xp.reshape(nb_pad, rows, _LANES))
     q, idx, scales = q[:nb], idx[:nb].reshape(-1), scales[:nb, 0]
     if value_dtype == "int4":
         q = pack_nibbles(q)          # per-block rows -> wire bytes
@@ -344,7 +389,8 @@ def wan_decode_pallas(
     # k_block from the index array — the int4 payload is nibble-packed, so
     # q's length is not k_block-shaped for every tier
     k_block = idx.shape[0] // (-(-n // min(block, n)))
-    block, k_block, lanes, nb, nb_pad = _geometry(n, block, k_block)
+    g = _DECODE_GROUP
+    block, k_block, rows, nb, nb_pad = _geometry(n, block, k_block, g)
     if value_dtype == "int4":
         q = unpack_nibbles(q.reshape(nb, -1), k_block)
 
@@ -353,15 +399,13 @@ def wan_decode_pallas(
 
     row = lambda b: (b, 0)                              # noqa: E731
     dense = pl.pallas_call(
-        functools.partial(_decode_kernel,
-                          chunk=_onehot_chunk(lanes, k_block),
-                          value_dtype=value_dtype),
-        grid=(nb_pad // ROWS,),
-        in_specs=[pl.BlockSpec((ROWS, k_block), row),
-                  pl.BlockSpec((ROWS, k_block), row),
-                  pl.BlockSpec((ROWS, 1), row)],
-        out_specs=pl.BlockSpec((ROWS, lanes), row),
-        out_shape=jax.ShapeDtypeStruct((nb_pad, lanes), jnp.float32),
+        functools.partial(_decode_kernel, value_dtype=value_dtype),
+        grid=(nb_pad // g,),
+        in_specs=[pl.BlockSpec((g, k_block), row),
+                  pl.BlockSpec((g, k_block), row),
+                  pl.BlockSpec((g, 1), row)],
+        out_specs=pl.BlockSpec((g, rows, _LANES), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb_pad, rows, _LANES), jnp.float32),
         interpret=interpret,
     )(pad_rows(q), pad_rows(idx), pad_rows(scales))
-    return dense[:nb, :block].reshape(-1)[:n]
+    return dense.reshape(nb_pad, -1)[:nb, :block].reshape(-1)[:n]

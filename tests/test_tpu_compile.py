@@ -75,3 +75,12 @@ def test_codec_compiles_for_v5e_small_bucket(one_chip):
     for hlo in _compile_codec(one_chip, 300, k_per_block(300, 0.02),
                               "int8"):
         assert 'custom_call_target="tpu_custom_call"' in hlo
+
+
+def test_codec_compiles_for_v5e_above_128_winners(one_chip):
+    """n = 2^24, top-k 0.05: 205 winners a block, so the gather and the
+    scatter each take two passes of 128 slots."""
+    kb = k_per_block(DEFAULT_BLOCK, 0.05)
+    assert kb > 128
+    for hlo in _compile_codec(one_chip, 1 << 24, kb, "int8"):
+        assert 'custom_call_target="tpu_custom_call"' in hlo

@@ -7,6 +7,8 @@ equality against the ``ref.py`` oracles — the codec's selection key,
 tie-breaking and quantizer are specified to the bit (see
 ``repro.kernels.wan_codec``), so allclose would hide real drift.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,16 +31,65 @@ def _rand(n):
 # ------------------------------------------------------- kernel vs oracle
 
 
-@pytest.mark.parametrize("n,k_block,block", [
-    (4096, 41, 1024),
-    (8192, 82, 4096),
-    (1000, 16, 256),      # non-multiple of block
-    (300, 8, 512),        # single short block
-    (5000, 12, 1024),     # padded tail block
-    (9000, 50, 4096),     # padded tail + partial row group
-])
-def test_encode_kernel_matches_oracle_exactly(n, k_block, block):
-    x = _rand(n)
+def _codec_input(n, block, layout):
+    """A codec input whose winners fall in the named layout over each
+    block's (block / 128, 128) tile of rows and lanes."""
+    x = np.asarray(_rand(n))
+    if layout is None:
+        return jnp.asarray(x)
+    x = x * 0.01
+    starts = range(0, n - block + 1, block)
+    if layout == "one_row":           # row 5's magnitudes dominate, all
+        for b in starts:              # negative: its other lanes sum -0s
+            x[b + 640:b + 768] = -10.0 - np.abs(x[b + 640:b + 768])
+    elif layout == "row_each":        # one winner per 128-lane row
+        for b in starts:
+            for r in range(block // 128):
+                x[b + 128 * r + (37 * r) % 128] = 10.0 + r
+    elif layout == "ties_cross_row":  # the tie cut falls past lane 127,
+        for b in starts:              # with winners above T after the ties
+            x[b + 100:b + 161] = 0.5
+            x[b + 200:b + 210] = 5.0
+    elif layout == "signed_zeros":    # -0 winners after the negative ones
+        x[:] = -0.0
+        x[5::97] = -1.0 - np.abs(x[5::97])
+    return jnp.asarray(x, jnp.float32)
+
+
+def _assert_same_bits(a, b):
+    """Decoded outputs agree to the bit, signed zeros included."""
+    np.testing.assert_array_equal(np.asarray(a).view(np.uint32),
+                                  np.asarray(b).view(np.uint32))
+
+
+# (n, k_block, block, layout): random normal input unless a layout is named
+_KERNEL_CASES = [
+    pytest.param(8192, 82, 4096, "one_row", id="winners-in-one-row"),
+    pytest.param(8192, 32, 4096, "row_each", id="one-winner-per-row"),
+    pytest.param(16384, k_per_block(4096, 0.05), 4096, None,
+                 id="k-above-128"),
+    pytest.param(1000, 6, 64, None, id="block-64"),
+    pytest.param(5000, 20, 1000, None, id="block-1000"),
+    pytest.param(512, 40, 256, "ties_cross_row", id="ties-span-two-rows"),
+    pytest.param(512, 40, 256, "signed_zeros", id="signed-zeros"),
+    pytest.param(394, 1, 100, "signed_zeros", id="one-negative-winner"),
+]
+
+
+@pytest.mark.parametrize("n,k_block,block,layout", [
+    pytest.param(4096, 41, 1024, None, id="4096-41-1024"),
+    pytest.param(8192, 82, 4096, None, id="8192-82-4096"),
+    # non-multiple of block
+    pytest.param(1000, 16, 256, None, id="1000-16-256"),
+    # single short block
+    pytest.param(300, 8, 512, None, id="300-8-512"),
+    # padded tail block
+    pytest.param(5000, 12, 1024, None, id="5000-12-1024"),
+    # padded tail + partial group of blocks
+    pytest.param(9000, 50, 4096, None, id="9000-50-4096"),
+] + _KERNEL_CASES)
+def test_encode_kernel_matches_oracle_exactly(n, k_block, block, layout):
+    x = _codec_input(n, block, layout)
     q1, i1, s1 = wan_encode_pallas(x, k_block, block=block, interpret=True)
     q2, i2, s2 = ref.wan_encode(x, k_block, block=block)
     np.testing.assert_array_equal(np.asarray(q1), np.asarray(q2))
@@ -46,7 +97,7 @@ def test_encode_kernel_matches_oracle_exactly(n, k_block, block):
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     d1 = wan_decode_pallas(q1, i1, s1, n, block=block, interpret=True)
     d2 = ref.wan_decode(q2, i2, s2, n, block=block)
-    np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
+    _assert_same_bits(d1, d2)
 
 
 def test_encode_handles_ties_and_zero_blocks():
@@ -90,28 +141,55 @@ def test_selection_energy_close_to_exact_topk():
     assert np.sum(d_codec ** 2) >= 0.9 * np.sum(d_exact ** 2)
 
 
+def _largest_kernel_value_bytes(fn, *args):
+    """Bytes of the largest value computed inside any Pallas kernel that
+    ``fn`` calls: what one grid step holds in VMEM at its high-water mark."""
+    from jax.extend import core
+
+    sizes = [0]
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if inside:
+                sizes.extend(v.aval.size * v.aval.dtype.itemsize
+                             for v in eqn.outvars if hasattr(v.aval, "shape"))
+            for p in eqn.params.values():
+                sub = p.jaxpr if isinstance(p, core.ClosedJaxpr) else p
+                if isinstance(sub, core.Jaxpr):
+                    walk(sub, inside or eqn.primitive.name == "pallas_call")
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return max(sizes)
+
+
 def test_high_k_auto_caps_onehot_tile_and_stays_exact():
-    """At aggressive fractions the (k_block, chunk) one-hot tile is the
-    VMEM high-water mark; the gather must walk the block in lane chunks to
-    keep the compiled TPU path under budget, without changing results
-    (tiling is semantics-free).
+    """At aggressive fractions (205 winners a block) the factored gather
+    and scatter walk the slots 128 at a time, so no value of a grid step
+    grows with k: the largest is one pass's gathered (keys | codes) tile,
+    (blocks per step, 2 x 128, 128) f32.  Chunking is semantics-free.
     """
-    from repro.kernels.wan_codec import _ONEHOT_BUDGET_BYTES, _onehot_chunk
+    from repro.kernels import wan_codec as wc
 
     block = 4096
     kb = k_per_block(block, 0.05)            # 205 winners/block
-    chunk = _onehot_chunk(block, kb)
-    assert chunk * 256 * 4 <= _ONEHOT_BUDGET_BYTES   # kb pads to 256 lanes
-    assert chunk < block and chunk % 128 == 0  # the cap actually engaged
+    assert kb > 128
     x = _rand(1 << 16)
-    q1, i1, s1 = wan_encode_pallas(x, kb, block=block, interpret=True)
+    tile = 2 * 128 * 128 * 4                 # one block's (keys | codes)
+    enc = functools.partial(wan_encode_pallas, k_block=kb, block=block,
+                            interpret=True)
+    assert _largest_kernel_value_bytes(enc, x) <= wc._ENCODE_GROUP * tile
+    q1, i1, s1 = enc(x)
     q2, i2, s2 = ref.wan_encode(x, kb, block=block)
     np.testing.assert_array_equal(np.asarray(q1), np.asarray(q2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
-    d1 = wan_decode_pallas(q1, i1, s1, 1 << 16, block=block, interpret=True)
+    dec = functools.partial(wan_decode_pallas, n=1 << 16, block=block,
+                            interpret=True)
+    assert (_largest_kernel_value_bytes(dec, q1, i1, s1)
+            <= wc._DECODE_GROUP * tile)
+    d1 = dec(q1, i1, s1)
     d2 = ref.wan_decode(q2, i2, s2, 1 << 16, block=block)
-    np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
+    _assert_same_bits(d1, d2)
 
 
 def test_ops_dispatch_oracle_equals_kernel():
@@ -130,13 +208,17 @@ def test_ops_dispatch_oracle_equals_kernel():
 
 
 @pytest.mark.parametrize("value_dtype", ["int8", "fp8", "int4"])
-@pytest.mark.parametrize("n,k_block,block", [
-    (4096, 41, 1024),     # odd k_block: int4 pads one zero nibble per block
-    (5000, 12, 1024),     # padded tail block
-    (300, 8, 512),        # single short block
-])
-def test_tier_kernel_matches_oracle_exactly(value_dtype, n, k_block, block):
-    x = _rand(n)
+@pytest.mark.parametrize("n,k_block,block,layout", [
+    # odd k_block: int4 pads one zero nibble per block
+    pytest.param(4096, 41, 1024, None, id="4096-41-1024"),
+    # padded tail block
+    pytest.param(5000, 12, 1024, None, id="5000-12-1024"),
+    # single short block
+    pytest.param(300, 8, 512, None, id="300-8-512"),
+] + _KERNEL_CASES)
+def test_tier_kernel_matches_oracle_exactly(value_dtype, n, k_block, block,
+                                            layout):
+    x = _codec_input(n, block, layout)
     q1, i1, s1 = wan_encode_pallas(x, k_block, block=block,
                                    value_dtype=value_dtype, interpret=True)
     q2, i2, s2 = ref.wan_encode(x, k_block, block=block,
@@ -148,7 +230,7 @@ def test_tier_kernel_matches_oracle_exactly(value_dtype, n, k_block, block):
     d1 = wan_decode_pallas(q1, i1, s1, n, block=block,
                            value_dtype=value_dtype, interpret=True)
     d2 = ref.wan_decode(q2, i2, s2, n, block=block, value_dtype=value_dtype)
-    np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
+    _assert_same_bits(d1, d2)
 
 
 def test_int4_payload_is_nibble_packed():
