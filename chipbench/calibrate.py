@@ -72,7 +72,7 @@ def main(argv=None) -> None:
     for s, kind, precision, fault in variants:
         pool = data.batch_pool(s, int(traf["pool"]), prog.pods, prog.rows,
                                prog.seq, conf["model"]["vocab_size"])
-        args_ = (conf["model"], conf["reference"], traf["sync"],
+        args_ = (conf["model"], cell.reference, traf["sync"],
                  float(conf["lr"]), run.key_for(s), pool[:n_check])
         if s not in refs:
             refs[s] = reference.train(*args_, devices=devices)

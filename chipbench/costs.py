@@ -1,9 +1,10 @@
 """Operations and bytes the work needs, computed from shapes.
 
 - ``train_flops_per_token``: model FLOPs of one training token (forward and
-  backward, three times the forward), no recomputation counted.  Products
-  count 2 operations per multiply-add.  Attention and the SSD scan count
-  the causal half of their quadratic terms.
+  backward, three times the forward), no recomputation counted.  Each
+  layer's forward is counted by the configuration's reference module
+  (``flops_per_token``, ``chipbench/reference``), beside the math it
+  counts; the head here.  Products count 2 operations per multiply-add.
 - ``codec_round``: what one ASGD-GA codec round's kernels must read and
   write (one encode and two decodes per pod: the sender's own
   reconstruction for error feedback, and the peer's message), and the
@@ -11,39 +12,16 @@
 """
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict
 
 
-def _ssm_forward(m: Dict) -> float:
-    D = m["d_model"]
-    d_in = m["ssm.expand"] * D
-    N, P, G = m["ssm.state_dim"], m["ssm.head_dim"], m["ssm.n_groups"]
-    H, W, L = d_in // P, m["ssm.conv_width"], m["ssm.chunk_size"]
-    proj = 2 * D * (2 * d_in + 2 * G * N + H) + 2 * d_in * D
-    conv = 2 * W * (d_in + 2 * G * N)
-    # per token: C.B scores over half a chunk per group; per head the
-    # masked score-value product, the chunk-state write and its read-out
-    scan = G * 2 * N * (L / 2) + H * (2 * P * (L / 2) + 4 * P * N)
-    return proj + conv + scan
-
-
-def _attn_mlp_forward(m: Dict, seq: int) -> float:
-    D, H, K, Dh, F = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                      m["resolved_head_dim"], m["d_ff"])
-    proj = 2 * D * (H * Dh + 2 * K * Dh) + 2 * H * Dh * D
-    attn = 2 * 2 * H * Dh * (seq / 2)          # causal QK^T and PV
-    mlp = 3 * 2 * D * F
-    return proj + attn + mlp
-
-
-def train_flops_per_token(config: Dict, seq: int) -> float:
+def train_flops_per_token(config: Dict, seq: int,
+                          reference: ModuleType) -> float:
+    """``reference``: the configuration's reference module
+    (``spec.load_reference``, a cell's ``reference``)."""
     m = config["model"]
-    if config["reference"] == "mamba2":
-        layer = _ssm_forward(m)
-    elif config["reference"] == "gqa_swiglu":
-        layer = _attn_mlp_forward(m, seq)
-    else:
-        raise KeyError(f"no FLOP count for {config['reference']!r}")
+    layer = reference.flops_per_token(m, seq)
     head = 2 * m["d_model"] * m["vocab_size"]
     return 3.0 * (m["n_layers"] * layer + head)
 

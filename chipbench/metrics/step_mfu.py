@@ -7,6 +7,7 @@ from chipbench import costs
 
 def read(ctx):
     w = ctx.traced
-    flops = costs.train_flops_per_token(ctx.config, ctx.program.seq)
+    flops = costs.train_flops_per_token(ctx.config, ctx.program.seq,
+                                        ctx.cell.reference)
     rate = flops * w.tokens / w.seconds
     return 100.0 * rate / (ctx.chips * ctx.peaks["bf16_flops"])

@@ -14,5 +14,6 @@ def read(ctx):
     step_s = sum(durs) / len(durs) / 1e9
     p = ctx.program
     tokens = p.pods // ctx.chips * p.rows * p.seq
-    flops = costs.train_flops_per_token(ctx.config, p.seq) * tokens
+    flops = costs.train_flops_per_token(ctx.config, p.seq,
+                                        ctx.cell.reference) * tokens
     return 100.0 * flops / step_s / ctx.peaks["bf16_flops"]

@@ -34,6 +34,19 @@ def sizes(model: Dict) -> Dict:
                 eps=model["norm_eps"], theta=model["rope_theta"])
 
 
+def flops_per_token(model: Dict, seq: int) -> float:
+    """Forward FLOPs of one token through one layer (``chipbench/reference``):
+    the four attention projections, causal attention over ``seq``
+    positions, and the three SwiGLU products."""
+    D, H, K, Dh, F = (model["d_model"], model["n_heads"],
+                      model["n_kv_heads"], model["resolved_head_dim"],
+                      model["d_ff"])
+    proj = 2 * D * (H * Dh + 2 * K * Dh) + 2 * H * Dh * D
+    attn = 2 * 2 * H * Dh * (seq / 2)          # causal QK^T and PV
+    mlp = 3 * 2 * D * F
+    return proj + attn + mlp
+
+
 def init(key, model: Dict) -> Dict:
     """Parameters as the system under test draws them: the same key tree,
     shapes and dtypes; layer leaves stacked on a leading layer axis."""

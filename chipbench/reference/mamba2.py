@@ -40,6 +40,23 @@ def sizes(model: Dict) -> Dict:
                 n_layers=model["n_layers"], eps=model["norm_eps"])
 
 
+def flops_per_token(model: Dict, seq: int) -> float:
+    """Forward FLOPs of one token through one layer (``chipbench/reference``):
+    the projections, the causal convolution and the chunked scan; the scan's
+    cost per token does not depend on ``seq``."""
+    D = model["d_model"]
+    d_in = model["ssm.expand"] * D
+    N, P, G = (model["ssm.state_dim"], model["ssm.head_dim"],
+               model["ssm.n_groups"])
+    H, W, L = d_in // P, model["ssm.conv_width"], model["ssm.chunk_size"]
+    proj = 2 * D * (2 * d_in + 2 * G * N + H) + 2 * d_in * D
+    conv = 2 * W * (d_in + 2 * G * N)
+    # per token: C.B scores over half a chunk per group; per head the
+    # masked score-value product, the chunk-state write and its read-out
+    scan = G * 2 * N * (L / 2) + H * (2 * P * (L / 2) + 4 * P * N)
+    return proj + conv + scan
+
+
 def init(key, model: Dict) -> Dict:
     """Parameters as the system under test draws them: the same key tree,
     shapes and dtypes; layer leaves stacked on a leading layer axis."""

@@ -15,8 +15,9 @@ After step ``s`` (0-based) with ``(s + 1) % interval == 0`` the pods sync:
   of its own and its ring predecessor's.
 
 An SGD step computes in float32 and stores the result in the parameter's
-own dtype.  The model's loss and gradients come from the architecture
-module named by the configuration, in the precision asked for.
+own dtype.  The model's loss and gradients come from the reference module
+the configuration names (``spec.load_reference``), in the precision asked
+for.
 
 ``fault`` plants one of the faults the correctness check must catch, in
 the reference put in the program's place: ``"half_batch"`` takes the mean
@@ -27,15 +28,15 @@ residual of one round out of the next round's message.
 from __future__ import annotations
 
 import functools
+from types import ModuleType
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.reference import codec, gqa_swiglu, mamba2
+from chipbench.reference import codec
 
-ARCHITECTURES = {"mamba2": mamba2, "gqa_swiglu": gqa_swiglu}
 FAULTS = ("half_batch", "no_exchange", "ef_dropped")
 
 
@@ -84,10 +85,10 @@ def _unflat(flat: jnp.ndarray, like):
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(arch: str, model_items: Tuple, sync_items: Tuple, lr: float,
-              precision: str, fault: Optional[str]):
-    """The jitted pieces of one reference run, built once per setting."""
-    mod = ARCHITECTURES[arch]
+def _programs(mod: ModuleType, model_items: Tuple, sync_items: Tuple,
+              lr: float, precision: str, fault: Optional[str]):
+    """The jitted pieces of one reference run, built once per setting and
+    reference module ``mod``."""
     model, sync = dict(model_items), dict(sync_items)
     ga_scale = lr * sync.get("ga_lr_scale", 1.0)
 
@@ -121,10 +122,11 @@ def _programs(arch: str, model_items: Tuple, sync_items: Tuple, lr: float,
             jax.jit(lambda k: mod.init(k, model)))
 
 
-def train(model: Dict, arch: str, sync: Dict, lr: float, key,
+def train(model: Dict, ref: ModuleType, sync: Dict, lr: float, key,
           batches: List[Dict[str, np.ndarray]], precision: str = "fp32",
           fault: Optional[str] = None, devices=None) -> Dict:
-    """Run ``len(batches)`` steps from the seed's initial weights.
+    """Run ``len(batches)`` steps from the seed's initial weights, with the
+    model of the reference module ``ref`` (``spec.load_reference``).
 
     ``batches[i][name]`` is ``(n_pods, rows, seq)``.  Returns per-pod
     losses of every step, per-pod per-leaf norms of the first step's
@@ -149,7 +151,7 @@ def train(model: Dict, arch: str, sync: Dict, lr: float, key,
     exchange = fault != "no_exchange"
 
     (grad_fn, sgd, add, encode_round, apply_peer, average,
-     init) = _programs(arch, tuple(sorted(model.items())),
+     init) = _programs(ref, tuple(sorted(model.items())),
                        tuple(sorted(sync.items())), lr, precision, fault)
     devices = list(devices or jax.devices()[:1])
     dev = [devices[p % len(devices)] for p in range(n_pods)]

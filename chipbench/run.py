@@ -282,7 +282,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         prog_rec["resid"] = leaf_norms(prog.residual_tree(resid))
     del p0, pn, g1_host, resid
     gc.collect()
-    ref_rec = reference.train(conf["model"], conf["reference"],
+    ref_rec = reference.train(conf["model"], cell.reference,
                               traf["sync"], lr, key_for(seed),
                               pool[:n_check], devices=devices)
     numbers = check.compare(prog_rec, ref_rec)

@@ -19,6 +19,11 @@ dispatch).  Both are read from the same ``.xplane.pb`` that
 - only top-level operations are counted: one lying wholly inside another
   on the same device (the body of a ``while``) is part of its parent.
 
+Every operation keeps its whole ``op_name`` too, so that a model's own
+``jax.named_scope`` nested under ``train_forward`` (say ``moe_experts``) is
+read by ``named_ms`` without joining the trace again, also where it lies in
+the body of the layer loop, which top-level counting folds into the loop.
+
 A program traced without scopes, or a trace without the HLO, gives every
 operation the scope "", and a trace without ``repro.*`` spans gives no
 program spans: the readers then return None.
@@ -26,12 +31,13 @@ program spans: the readers then return None.
 from __future__ import annotations
 
 import bisect
+import functools
 import glob
 import os
 import re
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 from chipbench import trace
 
@@ -46,11 +52,16 @@ BACKWARD = "train_backward"
 
 @dataclass
 class Scoped:
-    """The top-level operations of a trace with their scopes, and the
-    program's host spans."""
+    """Every operation of a trace with its ``op_name`` ("" for none), in
+    ``_nesting_order``, and the program's host spans."""
     devices: List[int]
-    top: List[Tuple[trace.Event, str]] = field(default_factory=list)
+    ops: List[Tuple[trace.Event, str]] = field(default_factory=list)
     program_spans: List[trace.Event] = field(default_factory=list)
+
+    @functools.cached_property
+    def top(self) -> List[Tuple[trace.Event, str]]:
+        """The top-level operations with their scopes (``scope_of``)."""
+        return [(op, scope_of(name)) for op, name in _outermost(self.ops)]
 
 
 # ------------------------------------------------------------ the xplane
@@ -273,11 +284,11 @@ def scope_of(op_name: str) -> str:
 
 # ------------------------------------------------------------ the join
 
-def op_scopes(tr: trace.Trace, runs, programs: Dict[int, Tuple[str, str]]
-              ) -> Dict[int, str]:
+def op_names(tr: trace.Trace, runs, programs: Dict[int, Tuple[str, str]]
+             ) -> Dict[int, str]:
     """``id`` of each operation of an execution in ``runs``
-    (``executions``) -> the scope of its instruction in the HLO of that
-    execution's program (``hlo_programs``)."""
+    (``executions``) -> the ``op_name`` of its instruction in the HLO of
+    that execution's program (``hlo_programs``, ``hlo_op_names``)."""
     by_dev: Dict[int, List[trace.Event]] = defaultdict(list)
     for op in tr.ops:
         by_dev[op.device].append(op)
@@ -293,22 +304,30 @@ def op_scopes(tr: trace.Trace, runs, programs: Dict[int, Tuple[str, str]]
         b = bisect.bisect_left(starts[dev], hi)
         for op in by_dev[dev][a:b]:
             if op.module == module:
-                out[id(op)] = scope_of(names[pid].get(op.name, ""))
+                out[id(op)] = names[pid].get(op.name, "")
     return out
+
+
+def _outermost(ops: Iterable[Tuple[trace.Event, str]]
+               ) -> Iterator[Tuple[trace.Event, str]]:
+    """Of ``(op, op_name)`` pairs sorted by ``_nesting_order``, those whose
+    op does not lie wholly inside another of theirs on the same device."""
+    end: Dict[int, float] = {}
+    for op, name in ops:
+        if op.end > end.get(op.device, float("-inf")):
+            end[op.device] = op.end
+            yield op, name
+
+
+def _nesting_order(pair: Tuple[trace.Event, str]):
+    op = pair[0]
+    return (op.device, op.start, -op.dur)
 
 
 def top_level(tr: trace.Trace) -> List[trace.Event]:
     """Operations not lying wholly inside another on the same device."""
-    out: List[trace.Event] = []
-    for d in tr.devices:
-        end = float("-inf")
-        for op in sorted((e for e in tr.ops if e.device == d),
-                         key=lambda e: (e.start, -e.dur)):
-            if op.end <= end:
-                continue
-            out.append(op)
-            end = op.end
-    return out
+    return [op for op, _ in _outermost(sorted(((op, "") for op in tr.ops),
+                                              key=_nesting_order))]
 
 
 def from_xspace(tr: trace.Trace, xspace: bytes) -> Scoped:
@@ -317,10 +336,11 @@ def from_xspace(tr: trace.Trace, xspace: bytes) -> Scoped:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_serialized_xspace(xspace)
-    scope = op_scopes(tr, executions(data), hlo_programs(xspace))
+    names = op_names(tr, executions(data), hlo_programs(xspace))
     return Scoped(
         devices=tr.devices,
-        top=[(op, scope.get(id(op), "")) for op in top_level(tr)],
+        ops=sorted(((op, names.get(id(op), "")) for op in tr.ops),
+                   key=_nesting_order),
         program_spans=program_spans(data))
 
 
@@ -359,6 +379,36 @@ def scope_ms(sc: Scoped, scope: str, count: int) -> Optional[float]:
     and per ``count`` (the traced steps or rounds), in ms; None where no
     operation has the scope."""
     durs = [op.dur for op, s in sc.top if s == scope]
+    if not durs:
+        return None
+    return sum(durs) / len(sc.devices) / count / 1e6
+
+
+def named_ms(sc: Scoped, name: str, count: int,
+             backward: bool) -> Optional[float]:
+    """Device time of the operations whose ``op_name`` holds ``name`` as a
+    whole scope component, per device and per ``count``, in ms; None where
+    none does.  A component lies between two ``/`` and counts with the
+    transformations wrapped around it: ``train_forward`` is one of
+    ``vmap(jvp(train_forward))``.  ``backward`` True keeps the operations
+    under a ``transpose(`` (the backward pass, remat in it), False the
+    others.  Of these, an operation lying wholly inside another
+    on its device is counted with it, as ``top_level`` counts; one in the
+    body of a loop that does not hold the scope (the layer loop) is
+    counted itself.  Unlike ``scope_ms`` this reads any scope at any
+    depth: ``named_ms(sc, "moe_experts", steps, False)`` is the forward
+    time of a model's own ``moe_experts`` scope, wherever it lies under
+    ``train_forward``."""
+    pattern = re.compile(r"(?:^|/)(?:[\w.-]*\()*" + re.escape(name) +
+                         r"\)*(?=/|$)")
+
+    def holds(op_name: str) -> bool:
+        m = pattern.search(op_name)
+        return m is not None and \
+            ("transpose(" in op_name[:m.end()]) == backward
+
+    durs = [op.dur for op, _ in _outermost(pair for pair in sc.ops
+                                           if holds(pair[1]))]
     if not durs:
         return None
     return sum(durs) / len(sc.devices) / count / 1e6

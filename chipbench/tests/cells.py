@@ -1,5 +1,7 @@
 """Small cells for CPU tests: a scratch benchmark root holding the
-benchmark's readers and peaks and configurations cut to smoke sizes."""
+benchmark's readers, references and peaks, and configurations cut to smoke
+sizes (``smoke/<config>.json``, one for each of ``BENCHMARK.json``'s
+configurations)."""
 from __future__ import annotations
 
 import json
@@ -9,31 +11,18 @@ from typing import Dict, List
 
 from chipbench.spec import ROOT
 
-MAMBA = {
-    "name": "mamba2-smoke", "arch": "mamba2-1.3b", "reference": "mamba2",
-    "overrides": {"n_layers": 2, "d_model": 128, "vocab_size": 256,
-                  "vocab_multiple": 64,
-                  "ssm": {"state_dim": 16, "head_dim": 16,
-                          "chunk_size": 16}},
-    "model": {"n_layers": 2, "d_model": 128, "vocab_size": 256,
-              "padded_vocab": 256, "ssm.state_dim": 16, "ssm.head_dim": 16,
-              "ssm.expand": 2, "ssm.n_groups": 1, "ssm.conv_width": 4,
-              "ssm.chunk_size": 16, "tie_embeddings": True,
-              "norm_eps": 1e-06, "param_dtype": "bfloat16",
-              "compute_dtype": "bfloat16"},
-    "optimizer": "sgd", "lr": 0.02}
+SMOKE = Path(__file__).parent / "smoke"
 
-GRANITE = {
-    "name": "granite-smoke", "arch": "granite-8b", "reference": "gqa_swiglu",
-    "overrides": {"n_layers": 2, "d_model": 128, "n_heads": 4,
-                  "n_kv_heads": 2, "d_ff": 256, "vocab_size": 256,
-                  "vocab_multiple": 64},
-    "model": {"n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv_heads": 2,
-              "resolved_head_dim": 32, "d_ff": 256, "vocab_size": 256,
-              "padded_vocab": 256, "rope_theta": 10000000.0,
-              "tie_embeddings": False, "norm_eps": 1e-06, "pos_embed": "rope",
-              "param_dtype": "bfloat16", "compute_dtype": "bfloat16"},
-    "optimizer": "sgd", "lr": 0.02}
+
+def smoke(config: str) -> Dict:
+    """The smoke-size stand-in of the benchmark's configuration ``config``:
+    ``smoke/<config>.json``, the same model family and reference at widths
+    a CPU test run holds."""
+    return json.loads((SMOKE / f"{config}.json").read_text())
+
+
+MAMBA = smoke("mamba2-1.3b.l4")
+GRANITE = smoke("granite-8b.l2.v6144")
 
 GA = {"layout": "stacked", "pods": 2, "rows_per_pod": 2, "seq": 32,
       "sync": {"strategy": "asgd_ga", "interval": 2, "compress_topk": 0.02,
@@ -63,8 +52,10 @@ def make_root(tmp: Path, cells: List[Dict], limits: Dict = None,
     root = Path(tmp)
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     (root / "chipbench").mkdir(parents=True, exist_ok=True)
-    shutil.copytree(ROOT / "chipbench" / "metrics",
-                    root / "chipbench" / "metrics", dirs_exist_ok=True)
+    for d in ("metrics", "reference"):
+        shutil.copytree(ROOT / "chipbench" / d, root / "chipbench" / d,
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     peaks = json.loads((ROOT / "chipbench" / "peaks.json").read_text())
     # a nominal entry so that the CPU backend can stand in for a chip
     peaks["devices"]["cpu"] = dict(peaks["devices"]["TPU v5 lite"])

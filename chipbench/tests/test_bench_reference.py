@@ -1,15 +1,20 @@
-"""The plain references against the program at smoke sizes (float32)."""
+"""The plain references against the program at smoke sizes, for every
+configuration in ``BENCHMARK.json`` (its smoke file,
+``smoke/<config>.json``; a configuration without one fails here)."""
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import data, run
-from chipbench.reference import codec, gqa_swiglu, mamba2
+from chipbench import data, run, spec
+from chipbench.reference import codec, mamba2
 from chipbench.reference import train as reference
 from chipbench.tests import cells
 
-CONFIGS = {"mamba2": cells.MAMBA, "gqa_swiglu": cells.GRANITE}
+CONFIGS = sorted(c["name"] for c in json.loads(
+    (spec.ROOT / "BENCHMARK.json").read_text())["configs"])
 
 
 def program_model(conf):
@@ -22,13 +27,18 @@ def program_model(conf):
     return cfg, get_model_fns(arch.module)
 
 
-@pytest.mark.parametrize("arch", sorted(CONFIGS))
+def reference_of(conf):
+    return spec.load_reference(spec.ROOT, conf["reference"])
+
+
+@pytest.mark.parametrize("config", CONFIGS)
 @pytest.mark.parametrize("f32", [False, True])
-def test_init_draws_the_programs_weights(arch, f32):
-    conf = cells.float32(CONFIGS[arch]) if f32 else CONFIGS[arch]
+def test_init_draws_the_programs_weights(config, f32):
+    conf = cells.smoke(config)
+    conf = cells.float32(conf) if f32 else conf
     cfg, fns = program_model(conf)
     key = run.key_for(2**31 + 5)
-    mine = reference.ARCHITECTURES[arch].init(key, conf["model"])
+    mine = reference_of(conf).init(key, conf["model"])
     theirs = fns.init_params(key, cfg)
     assert jax.tree.structure(mine) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
@@ -37,11 +47,11 @@ def test_init_draws_the_programs_weights(arch, f32):
                                       np.asarray(b, np.float32))
 
 
-@pytest.mark.parametrize("arch", sorted(CONFIGS))
-def test_loss_and_gradients_match_the_program(arch):
-    conf = cells.float32(CONFIGS[arch])
+@pytest.mark.parametrize("config", CONFIGS)
+def test_loss_and_gradients_match_the_program(config):
+    conf = cells.float32(cells.smoke(config))
     cfg, fns = program_model(conf)
-    mod = reference.ARCHITECTURES[arch]
+    mod = reference_of(conf)
     params = mod.init(run.key_for(7), conf["model"])
     b = data.token_rows(3, 0, 0, 2, 32, conf["model"]["vocab_size"])
     b = {k: jnp.asarray(v) for k, v in b.items()}
@@ -115,18 +125,18 @@ def _program_run(conf, traffic, n_steps, seed):
     return np.stack(losses), p0, jax.device_get(state.params), pool, resid
 
 
-@pytest.mark.parametrize("arch,traffic", [("mamba2", cells.GA),
-                                          ("gqa_swiglu", cells.MA),
-                                          ("mamba2", cells.MA)])
-def test_training_and_sync_match_the_program(arch, traffic):
+@pytest.mark.parametrize("config,traffic", [
+    ("mamba2-1.3b.l4", cells.GA), ("granite-8b.l2.v6144", cells.MA),
+    ("mamba2-1.3b.l4", cells.MA)])
+def test_training_and_sync_match_the_program(config, traffic):
     """SGD, the codec round with error feedback and the ring average: the
     reference's losses, weight changes and residual follow the program's
     through two sync rounds and past them, in float32."""
-    conf = cells.float32(CONFIGS[arch])
+    conf = cells.float32(cells.smoke(config))
     n = 2 * traffic["sync"]["interval"] + 1
     losses, p0, pn, pool, resid = _program_run(conf, traffic, n, 11)
-    ref = reference.train(conf["model"], arch, traffic["sync"], conf["lr"],
-                          run.key_for(11), pool)
+    ref = reference.train(conf["model"], reference_of(conf),
+                          traffic["sync"], conf["lr"], run.key_for(11), pool)
     np.testing.assert_allclose(losses, ref["losses"], rtol=5e-5)
     change = run.leaf_norms(pn, minus=p0)
     np.testing.assert_allclose(change, ref["change"], rtol=2e-3,

@@ -20,6 +20,17 @@ SCOPE_READERS = ("train_fwd_ms", "train_bwd_ms", "train_update_ms",
                  "sync_encode_ms", "sync_ef_ms", "sync_ring_ms",
                  "sync_apply_ms")
 ROUNDS = {"ga": 2, "ma": 1}
+# what the eight scope readers read from the committed traces before each
+# top-level operation kept its whole ``op_name``
+PINNED = {
+    "ga": {"train_fwd_ms": 4.1016975, "train_bwd_ms": 8.123152875,
+           "train_update_ms": 0.612272375, "sync_encode_ms": 59.29687125,
+           "sync_ef_ms": 0.9700865, "sync_ring_ms": 5.1231335,
+           "sync_apply_ms": 1.64062875, "trainer_idle_ms": 0.54110475},
+    "ma": {"train_fwd_ms": 5.56883475, "train_bwd_ms": 12.512004,
+           "train_update_ms": 0.225989, "sync_encode_ms": None,
+           "sync_ef_ms": None, "sync_ring_ms": None,
+           "sync_apply_ms": 0.167349, "trainer_idle_ms": 0.5374905}}
 
 
 def unpack(tmp: Path, raw: bytes) -> Path:
@@ -85,7 +96,7 @@ def test_each_execution_is_read_against_its_own_program(traced):
     name, tr, _, hlo, _ = traced
     runs = [r for r in scopes.executions(ProfileData.from_serialized_xspace(
         raw_of(name))) if r[3] in PROGRAMS]
-    scope = scopes.op_scopes(tr, runs, hlo)
+    named = scopes.op_names(tr, runs, hlo)
     # the window runs the train step as two executables on a pod mesh
     # (after a train step and after a round), as one stacked
     train = {pid for *_, mod, pid in runs if mod == PROGRAMS[0]}
@@ -96,10 +107,9 @@ def test_each_execution_is_read_against_its_own_program(traced):
         run = [op for op in tr.ops if op.device == dev and
                op.module == mod and lo <= op.start < hi]
         assert run and all(op.name in names for op in run), (mod, pid)
-        assert all(scope[id(op)] == scopes.scope_of(names[op.name])
-                   for op in run)
+        assert all(named[id(op)] == names[op.name] for op in run)
     # every operation of the two programs is read
-    assert len(scope) == sum(op.module in PROGRAMS for op in tr.ops)
+    assert len(named) == sum(op.module in PROGRAMS for op in tr.ops)
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
@@ -136,6 +146,111 @@ def test_readers(traced, reading):
     lo, hi = ctx.traced.lo, ctx.traced.hi
     idle = sum(hi - lo - trace.busy_ns(tr, d, lo, hi) for d in tr.devices)
     assert values["trainer_idle_ms"] < idle / chips / 4 / 1e6
+
+
+def test_readers_read_as_pinned(traced, reading):
+    name = traced[0]
+    assert {m: reading(m) for m in PINNED[name]} == PINNED[name]
+
+
+def test_named_ms_reads_the_scopes(traced):
+    """``named_ms`` on a scope of ``SCOPES`` reads at least what
+    ``scope_ms`` gives it: ``scope_ms`` gives an op only its first scope,
+    and leaves out an op lying inside another that does not hold it (on
+    the CPU, ops that run side by side); ``train_forward`` split by
+    ``transpose(`` gives the two passes."""
+    _, _, sc, _, _ = traced
+    pairs = [(scopes.named_ms(sc, s, 1, backward=False),
+              scopes.scope_ms(sc, s, 1)) for s in scopes.SCOPES]
+    pairs += [(scopes.named_ms(sc, "train_forward", 1, backward=True),
+               scopes.scope_ms(sc, scopes.BACKWARD, 1))]
+    for named, first in pairs:
+        assert (named is None) == (first is None)
+        assert first is None or named >= first
+    assert scopes.named_ms(sc, "no_such_scope", 1, backward=False) is None
+    # a component matches whole: a prefix of a scope's name is none
+    assert scopes.named_ms(sc, "train_forw", 1, backward=False) is None
+
+
+def test_named_ms_reads_a_scope_nested_under_train_forward(tmp_path):
+    """A model's own ``jax.named_scope`` inside the body of its layer loop,
+    under ``train_forward``, traced on the CPU: its forward and backward
+    times are read, and each is a part of the pass it lies in."""
+    import jax.numpy as jnp
+    from repro.core.sync import SyncConfig
+    from repro.training.trainer import Trainer, TrainerConfig
+
+    V, D, F, L = 64, 32, 128, 4
+
+    def init(key):
+        k1, k2, k3 = jax.random.split(key, 3)
+        return {"embed": jax.random.normal(k1, (V, D)) * 0.1,
+                "w_in": jax.random.normal(k2, (L, D, F)) * 0.1,
+                "w_out": jax.random.normal(k3, (L, F, D)) * 0.1}
+
+    def layer(x, w):
+        with jax.named_scope("mlp_experts"):
+            h = jnp.square(jax.nn.relu(x @ w[0])) @ w[1]
+        return x + h, None
+
+    def loss(p, batch):
+        x, _ = jax.lax.scan(layer, p["embed"][batch["tokens"]],
+                            (p["w_in"], p["w_out"]))
+        logits = x @ p["embed"].T
+        gold = jnp.take_along_axis(logits, batch["labels"][..., None],
+                                   axis=-1)[..., 0]
+        return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - gold), {}
+
+    tr = Trainer(loss, init, TrainerConfig(n_pods=2, optimizer="sgd",
+                                           lr=0.01,
+                                           sync=SyncConfig("ama", 1)))
+    state = tr.init_state(jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 4, 65), 0, V)
+    batch = {"tokens": tokens[..., :-1], "labels": tokens[..., 1:]}
+    state = tr.maybe_sync(tr.train_step(state, batch)[0], 0)
+    steps = 2
+    with jax.profiler.trace(str(tmp_path)):
+        for step in range(steps):
+            state, _ = tr.train_step(state, batch)
+            state = tr.maybe_sync(state, step)
+        jax.block_until_ready(state)
+    raw = next(tmp_path.glob("**/*.xplane.pb")).read_bytes()
+    sc = scopes.from_xspace(trace.from_profile(
+        ProfileData.from_serialized_xspace(raw)), raw)
+    fwd = scopes.named_ms(sc, "mlp_experts", steps, backward=False)
+    bwd = scopes.named_ms(sc, "mlp_experts", steps, backward=True)
+    assert 0 < fwd <= scopes.scope_ms(sc, "train_forward", steps)
+    assert 0 < bwd <= scopes.scope_ms(sc, scopes.BACKWARD, steps)
+
+
+def test_named_ms_counts_an_op_once_and_reads_loop_bodies():
+    """On a chip a loop's body runs inside the loop's own event, which
+    top-level counting keeps alone: a scope inside the body of a loop that
+    does not hold it is read from the body's ops, and an op inside one
+    that holds the scope too is counted with it."""
+    def op(name, start, dur, op_name):
+        return trace.Event(0, name, start, dur, "jit__train_step_impl"), \
+            op_name
+
+    fwd = "jit(f)/vmap(jvp(train_forward))"
+    bwd = "jit(f)/vmap(transpose(jvp(train_forward)))"
+    ops = [op("while.1", 0, 100, fwd + "/while"),
+           op("dot.1", 10, 30, fwd + "/while/body/moe_experts/dot_general"),
+           op("fusion.1", 50, 20, fwd + "/while/body/moe_experts/mul"),
+           op("fusion.2", 52, 5, fwd + "/while/body/moe_experts/add"),
+           op("fusion.3", 80, 10, fwd + "/while/body/norm/mul"),
+           op("while.2", 200, 300, bwd + "/while"),
+           op("dot.2", 210, 60, bwd + "/while/body/moe_experts/dot_general")]
+    sc = scopes.Scoped(devices=[0], ops=ops)
+    assert [e.name for e, _ in sc.top] == ["while.1", "while.2"]
+    assert scopes.named_ms(sc, "moe_experts", 1, backward=False) == 50e-6
+    assert scopes.named_ms(sc, "moe_experts", 1, backward=True) == 60e-6
+    assert scopes.named_ms(sc, "moe_experts", 2, backward=True) == 30e-6
+    assert scopes.named_ms(sc, "train_forward", 1, backward=False) == \
+        scopes.scope_ms(sc, "train_forward", 1) == 100e-6
+    assert scopes.named_ms(sc, "norm", 1, backward=False) == 10e-6
+    assert scopes.named_ms(sc, "norm", 1, backward=True) is None
+    assert scopes.named_ms(sc, "moe", 1, backward=False) is None
 
 
 def test_scope_of():
@@ -208,12 +323,17 @@ def test_readers_leave_a_program_without_scopes_out(monkeypatch, tmp_path):
 
 
 def test_trace_holds_the_compiled_programs(tmp_path):
+    import gc
+
     from repro.configs import get_arch
     from repro.core.sync import SyncConfig
     from repro.launch.context import wrap_loss
     from repro.models.registry import get_model_fns
     from repro.training.trainer import Trainer, TrainerConfig
 
+    # the trace holds every executable alive in the process: let those of
+    # earlier tests that are garbage go first
+    gc.collect()
     arch = get_arch("mamba2-1.3b")
     cfg = arch.smoke
     fns = get_model_fns(arch.module)

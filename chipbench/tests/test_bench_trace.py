@@ -53,10 +53,13 @@ def test_readers_on_the_trace(tr):
     # the model step's share over its own device time; the whole cycle's
     # over the traced window, which holds the rounds and the gaps too
     ctx.config, ctx.chips = cells.MAMBA, 1
+    ctx.cell = SimpleNamespace(reference=spec.load_reference(
+        spec.ROOT, cells.MAMBA["reference"]))
     ctx.program = SimpleNamespace(pods=2, rows=2, seq=32)
     ctx.traced.tokens = 4 * 2 * 2 * 32
     ctx.peaks = spec.load_peaks("TPU v5 lite")
-    flops = costs.train_flops_per_token(cells.MAMBA, 32) * 2 * 2 * 32
+    flops = costs.train_flops_per_token(cells.MAMBA, 32,
+                                        ctx.cell.reference) * 2 * 2 * 32
     mfu = spec.load_reader(spec.ROOT, "train_mfu")(ctx)
     assert mfu == pytest.approx(100 * flops / (step / 1e3) / 197e12)
     whole = spec.load_reader(spec.ROOT, "step_mfu")(ctx)
